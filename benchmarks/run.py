@@ -15,12 +15,13 @@ import os
 import pathlib
 import sys
 
-# Expose every CPU core as an XLA host device BEFORE jax initializes: the
-# sweep harness (core/sweep.py) shards independent grid cells across devices,
-# which is where the batched Table-1/4 path gets its multi-core wall-clock
-# win (the sequential baseline is inherently serial).  No-op off-CPU.
-if "--xla_force_host_platform_device_count" not in os.environ.get(
-        "XLA_FLAGS", ""):
+# On an explicit CPU run (JAX_PLATFORMS=cpu), expose every core as an XLA
+# host device BEFORE jax initializes: the sweep harness (core/sweep.py)
+# shards independent grid cells across devices.  Any other platform keeps
+# the devices JAX finds.
+if (os.environ.get("JAX_PLATFORMS") == "cpu"
+        and "--xla_force_host_platform_device_count" not in os.environ.get(
+            "XLA_FLAGS", "")):
     os.environ["XLA_FLAGS"] = (
         f"{os.environ.get('XLA_FLAGS', '')} "
         f"--xla_force_host_platform_device_count={os.cpu_count()}").strip()
@@ -60,8 +61,9 @@ def main() -> None:
     # persistent XLA compilation cache: repeat benchmark invocations skip the
     # sweep programs' compile entirely (the cache survives the process)
     import jax
-    jax.config.update("jax_compilation_cache_dir",
-                      str(json_dir / ".jax_cache"))
+
+    from repro.utils.jax_compat import use_compile_cache
+    use_compile_cache(str(pathlib.Path(__file__).resolve().parent.parent))
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.5)
 
     from benchmarks import (cohort_scale, convergence, faults_scale,

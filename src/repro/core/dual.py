@@ -22,6 +22,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.core.losses import Loss
+from repro.utils.jax_compat import F32_DOT, fp_barrier
 
 Array = jax.Array
 
@@ -92,17 +93,28 @@ def init_state(data: FederatedData) -> DualState:
 
 def compute_v(data: FederatedData, alpha: Array) -> Array:
     """v_t = sum_i alpha_t^i x_t^i  -- the only cross-node quantity."""
-    return jnp.einsum("tid,ti->td", data.X, alpha * data.mask)
+    return jnp.einsum("tid,ti->td", data.X, alpha * data.mask,
+                      precision=F32_DOT)
 
 
 def primal_weights(K: Array, v: Array) -> Array:
     """W(alpha) = (1/2) K V, rows are per-task weights w_t (m, d)."""
-    return 0.5 * K @ v
+    return 0.5 * jnp.matmul(K, v, precision=F32_DOT)
+
+
+def _quad(A: Array, V: Array) -> Array:
+    """sum_tt' A_tt' <V_t, V_t'> as one matmul, then a pinned mul+reduce.
+
+    Not a three-operand einsum: its contraction path runs on dots whose
+    rounding depends on the batch shape, so the vmapped sweep and a single
+    run disagreed in the last bits of the objectives."""
+    AV = fp_barrier(jnp.matmul(A, V, precision=F32_DOT))
+    return jnp.sum(fp_barrier(V * AV))
 
 
 def r_star(K: Array, v: Array) -> Array:
     """R*(X alpha) = (1/4) sum_tt' K_tt' <v_t, v_t'>."""
-    return 0.25 * jnp.einsum("td,ts,sd->", v, K, v)
+    return 0.25 * _quad(K, v)
 
 
 def dual_objective(data: FederatedData, loss: Loss, K: Array,
@@ -113,9 +125,12 @@ def dual_objective(data: FederatedData, loss: Loss, K: Array,
 
 def primal_objective(data: FederatedData, loss: Loss, abar: Array,
                      W: Array) -> Array:
-    z = jnp.einsum("tid,td->ti", data.X, W)
+    # mul+reduce, not a batched matvec: under the sweep's vmap, X is batched
+    # over shuffles only and W over (lambda, shuffle), and the dot XLA forms
+    # for that rounds differently from a single run's
+    z = jnp.sum(data.X * W[:, None, :], axis=-1)
     losses = loss.value(z, data.y) * data.mask
-    reg = jnp.einsum("td,ts,sd->", W, abar, W)
+    reg = _quad(abar, W)
     return jnp.sum(losses) + reg
 
 
@@ -129,7 +144,7 @@ def duality_gap(data: FederatedData, loss: Loss, abar: Array, K: Array,
 def per_task_error(data: FederatedData, W: Array,
                    X_test: Array, y_test: Array, mask_test: Array) -> Array:
     """Binary classification error per task (for Table 1/4 style reporting)."""
-    z = jnp.einsum("tid,td->ti", X_test, W)
+    z = jnp.einsum("tid,td->ti", X_test, W, precision=F32_DOT)
     wrong = (jnp.sign(z) != jnp.sign(y_test)) & (mask_test > 0)
     cnt = jnp.maximum(jnp.sum(mask_test, axis=1), 1.0)
     return jnp.sum(wrong, axis=1) / cnt

@@ -29,6 +29,7 @@ import numpy as np
 
 from repro.core.dual import FederatedData
 from repro.core.losses import Loss
+from repro.utils.jax_compat import F32_DOT
 
 Array = jax.Array
 
@@ -71,7 +72,7 @@ def _client_metrics(loss: Loss, W: Array, X: Array, y: Array,
     """
     from repro.core.dual import per_task_error
     err = per_task_error(None, W, X, y, mask)
-    z = jnp.einsum("tid,td->ti", X, W)
+    z = jnp.einsum("tid,td->ti", X, W, precision=F32_DOT)
     cnt = jnp.maximum(jnp.sum(mask, axis=-1), 1.0)
     lval = jnp.sum(loss.value(z, y) * mask, axis=-1) / cnt
     return err, lval

@@ -76,13 +76,21 @@ def _hinge_conj_neg(a, y):
     return -a * y
 
 
-def _hinge_delta(a, y, xg, qxx, eps):
+def _hinge_step(a, y, yxg, qxx, eps):
+    """Hinge coordinate update given the product ``yxg = y * <x, g>``.
+
+    Unpinned, so the compiled Pallas kernel (which Mosaic cannot lower
+    ``optimization_barrier`` in) shares the formula with ``_hinge_delta``."""
     abar = a * y
-    # barrier: forbid FMA-contracting y*xg into the subtraction, which would
-    # break bit-parity with the Pallas hinge kernel (same expression there)
-    step = (1.0 - fp_barrier(y * xg)) / jnp.maximum(qxx, eps)
+    step = (1.0 - yxg) / jnp.maximum(qxx, eps)
     abar_new = jnp.clip(abar + step, 0.0, 1.0)
     return (abar_new - abar) * y
+
+
+def _hinge_delta(a, y, xg, qxx, eps):
+    # barrier: forbid FMA-contracting y*xg into the subtraction, which would
+    # break bit-parity with the Pallas hinge kernel (same expression there)
+    return _hinge_step(a, y, fp_barrier(y * xg), qxx, eps)
 
 
 # ---------------------------------------------------------------------------

@@ -33,9 +33,15 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 
+from repro.utils.jax_compat import F32_DOT
+
 Array = jax.Array
 
 _JITTER = 1e-8
+
+
+def _mm(a: Array, b: Array) -> Array:
+    return jnp.matmul(a, b, precision=F32_DOT)
 
 
 def _sym(x: Array) -> Array:
@@ -46,14 +52,14 @@ def _psd_sqrt(s: Array, floor: float = 1e-10) -> Array:
     """Matrix square root of a PSD matrix via eigh."""
     w, q = jnp.linalg.eigh(_sym(s))
     w = jnp.maximum(w, floor)
-    return (q * jnp.sqrt(w)) @ q.T
+    return _mm(q * jnp.sqrt(w), q.T)
 
 
 def spd_inverse(a: Array, floor: float = 1e-10) -> Array:
     """Inverse of an SPD matrix with eigenvalue flooring (robust K computation)."""
     w, q = jnp.linalg.eigh(_sym(a))
     w = jnp.maximum(w, floor)
-    return (q / w) @ q.T
+    return _mm(q / w, q.T)
 
 
 class Regularizer:
@@ -71,7 +77,7 @@ class Regularizer:
     def penalty(self, W: Array, omega: Array) -> Array:
         """R(W, Omega) for the primal objective. W is (m, d) row-per-task."""
         abar = self.coupling(omega)
-        return jnp.einsum("td,st,sd->", W, abar, W)
+        return jnp.einsum("td,st,sd->", W, abar, W, precision=F32_DOT)
 
     def update_omega(self, W: Array, omega: Array) -> Array:
         """Central Omega-step given W (m, d). Default: fixed omega."""
@@ -93,7 +99,7 @@ class MeanRegularized(Regularizer):
     def init_omega(self, m: int) -> Array:
         eye = jnp.eye(m)
         c = eye - jnp.full((m, m), 1.0 / m)
-        return c @ c
+        return _mm(c, c)
 
     def coupling(self, omega: Array) -> Array:
         m = omega.shape[0]
@@ -123,7 +129,7 @@ class Clustered(Regularizer):
         space since W is (m, d)); eigenvalue water-filling: w_i = clip(
         sqrt(s_i)/nu - eta, 0, 1), nu by bisection on sum w_i(nu) = k.
         """
-        s_mat = W @ W.T
+        s_mat = _mm(W, W.T)
         svals, q = jnp.linalg.eigh(_sym(s_mat))
         svals = jnp.maximum(svals, 0.0)
         root = jnp.sqrt(svals + _JITTER)
@@ -159,7 +165,7 @@ class Clustered(Regularizer):
         # exactly as Probabilistic guards its trace normalization.
         m = W.shape[0]
         return jnp.where(jnp.sum(svals) > 1e-10,
-                         (q * w) @ q.T,
+                         _mm(q * w, q.T),
                          jnp.eye(m) * (self.k / m))
 
 
@@ -179,7 +185,7 @@ class Probabilistic(Regularizer):
         return self.lam * (spd_inverse(omega, floor=1e-6) + jnp.eye(m) / self.sigma2)
 
     def update_omega(self, W: Array, omega: Array) -> Array:
-        root = _psd_sqrt(W @ W.T)
+        root = _psd_sqrt(_mm(W, W.T))
         tr = jnp.trace(root)
         m = W.shape[0]
         # guard the cold-start W = 0 case: keep the uninformative prior
@@ -218,7 +224,7 @@ class Graphical(Regularizer):
                 + self.lam2 * jnp.sum(jnp.abs(omega)))
 
     def update_omega(self, W: Array, omega: Array) -> Array:
-        s_mat = self.lam * (W @ W.T)
+        s_mat = self.lam * _mm(W, W.T)
 
         def step(om, _):
             grad = s_mat - self.lam * self.d_scale * spd_inverse(om, floor=1e-6)
@@ -228,7 +234,7 @@ class Graphical(Regularizer):
             om = jnp.where(jnp.eye(om.shape[0], dtype=bool), om, off)
             # PSD projection with floor
             w, q = jnp.linalg.eigh(_sym(om))
-            om = (q * jnp.maximum(w, 1e-4)) @ q.T
+            om = _mm(q * jnp.maximum(w, 1e-4), q.T)
             return om, None
 
         omega, _ = jax.lax.scan(step, omega, None, length=self.ista_steps)

@@ -51,7 +51,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.core.losses import Loss
-from repro.utils.jax_compat import fp_barrier
+from repro.utils.jax_compat import F32_DOT, fp_barrier
 
 Array = jax.Array
 
@@ -61,8 +61,9 @@ def subproblem_value(loss: Loss, X_t: Array, y_t: Array, mask_t: Array,
                      q_t: Array) -> Array:
     """G_t(Delta; v, alpha) minus the constant c(alpha)."""
     conj = loss.conjugate_neg(alpha_t + dalpha_t, y_t) * mask_t
-    u = X_t.T @ (dalpha_t * mask_t)
-    return jnp.sum(conj) + jnp.dot(w_t, u) + 0.5 * q_t * jnp.dot(u, u)
+    u = jnp.matmul(X_t.T, dalpha_t * mask_t, precision=F32_DOT)
+    return (jnp.sum(conj) + jnp.dot(w_t, u, precision=F32_DOT)
+            + 0.5 * q_t * jnp.dot(u, u, precision=F32_DOT))
 
 
 #: point count at and above which the compact chunk accumulator is used: the
@@ -177,17 +178,25 @@ def _chunk_layout(idx: Array, n: int, max_steps: int, C: int) -> ChunkPlan:
 # imports these, so kernel and reference cannot drift.
 # ---------------------------------------------------------------------------
 
-def _chunk_gram(Xc: Array) -> Array:
-    """G_c = X_c X_c^T via dot_general: (C, d) @ (d, C) -> (C, C).
+def _pinned_gram(Xc: Array) -> Array:
+    return fp_barrier(jnp.sum(fp_barrier(Xc[:, None, :] * Xc[None, :, :]),
+                              axis=-1))
 
-    Safe for cross-engine parity because BOTH sides compute it the same way
-    on identical gathered values -- batched (vmapped) and single-instance
-    dot_general agree bitwise per slice (pinned by the parity tests), unlike
-    the per-step length-d dots of the v1 loop, whose fusion context varied.
-    fp_barrier forces the chunk tensor to materialize once: without it XLA
-    may rematerialize it per consumer with a context-dependent reduction
-    association (same reason as the per-product barriers, one level up)."""
-    return fp_barrier(jnp.matmul(Xc, Xc.T))
+
+def _chunk_gram(Xc: Array) -> Array:
+    """G_c = X_c X_c^T: (C, d) x (C, d) -> (C, C).
+
+    On the CPU a pinned mul+reduce, not ``dot_general``: XLA's CPU dot
+    picks its kernel by the batch shape, and a vmapped dot whose batch is 1
+    (a one-task federation, a 1x1 sweep grid) rounds differently from both
+    the unbatched and the wider batched dot.  The products are pinned
+    before the reduce (as in ``row_norms``), so every batch shape sums the
+    same rounded terms in the same order.  Elsewhere one float32 matmul
+    (the MXU on a TPU), where engines agree to a tolerance, not bits
+    (DESIGN.md section 2)."""
+    return jax.lax.platform_dependent(
+        Xc, cpu=_pinned_gram,
+        default=lambda x: jnp.matmul(x, x.T, precision=F32_DOT))
 
 
 def _chunk_rowdots(Xc: Array, r: Array) -> Array:
@@ -203,7 +212,7 @@ def _chunk_colsum(Xc: Array, deltas: Array) -> Array:
     This single reduction replaces C per-step axpys: it is the chunk's
     contribution to ``u`` and (scaled by q, behind its own barrier) to
     ``r``; fp_barrier pins the reduce's association across contexts."""
-    return fp_barrier(jnp.sum(Xc * deltas[:, None], axis=0))
+    return fp_barrier(jnp.sum(fp_barrier(Xc * deltas[:, None]), axis=0))
 
 
 def _carry_g(x_s: Array, r: Array) -> Array:
@@ -238,14 +247,19 @@ def _gram_chunk_r(r: Array, q_t: Array, colsum: Array) -> Array:
     return r + fp_barrier(q_t * colsum)
 
 
+@jax.jit
 def row_norms(X: Array) -> Array:
     """``||x_i||^2`` rows, barriered: THE xnorm2 used by every engine.
 
-    The barrier materializes the table so the reduce cannot be re-fused
-    into a consumer with a context-dependent partial-sum tree -- the hoisted
-    per-run table (``run_mocha``), the in-solver fallback, and the Pallas
-    wrapper's kernel input are then bit-identical by construction."""
-    return fp_barrier(jnp.sum(X * X, axis=-1))
+    The inner barrier rounds the squares before the reduce: inside a jit,
+    XLA's CPU backend contracts ``x*x`` into the reduce's adds as FMAs,
+    which an eager (op-by-op) call cannot, so without it the hoisted
+    per-run table (``run_mocha``, eager) and the in-jit hoist (the vmapped
+    sweep) differ by a ulp.  The outer barrier materializes the table so
+    the reduce cannot be re-fused into a consumer with a context-dependent
+    partial-sum tree -- the hoisted table, the in-solver fallback, and the
+    Pallas wrapper's kernel input are then bit-identical by construction."""
+    return fp_barrier(jnp.sum(fp_barrier(X * X), axis=-1))
 
 
 def _draw_coordinates(X_t: Array, mask_t: Array, key: Array,

@@ -17,7 +17,6 @@ the same budgets and keys (tested in tests/test_runtime.py).
 """
 from __future__ import annotations
 
-import inspect
 from functools import partial
 from typing import TYPE_CHECKING, Optional, Tuple
 
@@ -30,38 +29,24 @@ import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding
 from jax.sharding import PartitionSpec as P
 
-try:  # jax >= 0.6: top-level export, replication check spelled check_vma
-    from jax import shard_map as _shard_map
-except ImportError:  # pinned 0.4.x: experimental module, check_rep
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-_CHECK_KW = ("check_vma" if "check_vma"
-             in inspect.signature(_shard_map).parameters else "check_rep")
-
 from repro.core.dual import DualState, FederatedData
 from repro.core.losses import Loss
 from repro.core.subproblem import batched_local_sdca
+from repro.utils.jax_compat import F32_DOT
 
 Array = jax.Array
-
-
-def shard_map_compat(fn, mesh, in_specs, out_specs, check: bool = True):
-    """``shard_map`` across the jax 0.4.x -> 0.6+ API rename."""
-    return _shard_map(fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                      **{_CHECK_KW: check})
 
 
 def make_federated_mesh(n_shards: int | None = None) -> Mesh:
     """1-D mesh over the ``data`` axis for the MTL runtime."""
     devices = jax.devices()
     n = n_shards or len(devices)
-    try:  # newer jax: explicit Auto axis type
-        return jax.make_mesh((n,), ("data",),
-                             axis_types=(jax.sharding.AxisType.Auto,))
-    except (AttributeError, TypeError):
-        return jax.make_mesh((n,), ("data",))
+    return jax.make_mesh((n,), ("data",),
+                         axis_types=(jax.sharding.AxisType.Auto,))
 
 
+@partial(jax.jit, static_argnums=(0, 1, 2),
+         static_argnames=("comm_dtype", "gram"))
 def distributed_round(mesh: Mesh, loss: Loss, max_steps: int,
                       data: FederatedData, alpha: Array, v: Array,
                       K: Array, q_t: Array, budgets: Array, gamma: float,
@@ -79,7 +64,9 @@ def distributed_round(mesh: Mesh, loss: Loss, max_steps: int,
         validated in tests/test_runtime.py).
       gram: residual-mode override (``MochaConfig.gram_max_d`` resolved by
         the driver); None keeps the shared ``_solver_plan`` default.
-    Returns (alpha', v') with the same shardings.
+    Returns (alpha', v') with the same shardings.  Jitted on the static
+    (mesh, loss, max_steps, comm_dtype, gram), so every round after the
+    first reuses one compiled program.
     """
     task_sharded = P("data")
     replicated = P()
@@ -91,7 +78,7 @@ def distributed_round(mesh: Mesh, loss: Loss, max_steps: int,
     def shard_fn(X_sh, y_sh, mask_sh, xn_sh, alpha_sh, v_full, K_rows, q_sh,
                  budgets_sh, keys_sh):
         # local W rows for this shard's tasks: w_t = 1/2 sum_s K_ts v_s
-        W_sh = 0.5 * K_rows @ v_full
+        W_sh = 0.5 * jnp.matmul(K_rows, v_full, precision=F32_DOT)
         dalpha, u = batched_local_sdca(
             loss, X_sh, y_sh, mask_sh, alpha_sh, W_sh, q_sh, budgets_sh,
             keys_sh, max_steps, xnorm2=xn_sh, gram=gram)
@@ -101,7 +88,7 @@ def distributed_round(mesh: Mesh, loss: Loss, max_steps: int,
         du_full = du_full.astype(v_full.dtype)
         return alpha_sh + gamma * dalpha, v_full + gamma * du_full
 
-    fn = shard_map_compat(
+    fn = jax.shard_map(
         shard_fn, mesh=mesh,
         in_specs=(task_sharded, task_sharded, task_sharded, task_sharded,
                   task_sharded, replicated, task_sharded, task_sharded,
@@ -109,7 +96,7 @@ def distributed_round(mesh: Mesh, loss: Loss, max_steps: int,
         out_specs=(task_sharded, replicated),
         # the solver builds zero-initialized carries internally; their varying
         # manual axes are established by the first masked update
-        check=False,
+        check_vma=False,
     )
     return fn(data.X, data.y, data.mask, xnorm2, alpha, v, K, q_t, budgets,
               keys)
@@ -138,19 +125,27 @@ def run_mocha_distributed(data: FederatedData, reg: "Regularizer",
 
 def lower_federated_round(mesh: Mesh, loss: Loss, max_steps: int,
                           m: int, n_max: int, d: int):
-    """Lower (no execution) the distributed round for dry-run inspection."""
+    """Lower (no execution) the distributed round for dry-run inspection.
+
+    Every argument carries the ``NamedSharding`` the run gives it on
+    ``mesh`` (task-major state split over ``data``, v replicated), so the
+    mesh may hold described devices (``jax.experimental.topologies``) and
+    ``.compile()`` then compiles for that chip without one attached."""
     f32 = jnp.float32
-    sds = jax.ShapeDtypeStruct
-    data = FederatedData(X=sds((m, n_max, d), f32), y=sds((m, n_max), f32),
-                         mask=sds((m, n_max), f32))
-    args = (data, sds((m, n_max), f32), sds((m, d), f32), sds((m, m), f32),
-            sds((m,), f32), sds((m,), jnp.int32), 1.0,
+    task = NamedSharding(mesh, P("data"))
+    repl = NamedSharding(mesh, P())
+
+    def sds(shape, dtype=f32, sharding=task):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
+    data = FederatedData(X=sds((m, n_max, d)), y=sds((m, n_max)),
+                         mask=sds((m, n_max)), xnorm2=sds((m, n_max)))
+    args = (data, sds((m, n_max)), sds((m, d), sharding=repl),
+            sds((m, m)), sds((m,)), sds((m,), jnp.int32),
             sds((m, 2), jnp.uint32))
 
-    def step(data, alpha, v, K, q_t, budgets, gamma, keys):
+    def step(data, alpha, v, K, q_t, budgets, keys):
         return distributed_round(mesh, loss, max_steps, data, alpha, v, K,
-                                 q_t, budgets, gamma, keys)
+                                 q_t, budgets, 1.0, keys)
 
-    shardings = jax.tree_util.tree_map(
-        lambda _: None, args, is_leaf=lambda x: isinstance(x, sds))
-    return jax.jit(step, static_argnums=(6,)).lower(*args[:6], 1.0, args[7])
+    return jax.jit(step).lower(*args)

@@ -32,10 +32,8 @@ def _decode_kernel(q_ref, k_ref, v_ref, len_ref, o_ref, *, block_k: int,
     def body(kb, carry):
         acc, m_prev, l_prev = carry
         k0 = kb * block_k
-        k = pl.load(k_ref, (pl.dslice(k0, block_k), slice(None))
-                    ).astype(jnp.float32)
-        v = pl.load(v_ref, (pl.dslice(k0, block_k), slice(None))
-                    ).astype(jnp.float32)
+        k = k_ref[pl.ds(k0, block_k), :].astype(jnp.float32)
+        v = v_ref[pl.ds(k0, block_k), :].astype(jnp.float32)
         s = k @ q                                      # (block_k,)
         pos = k0 + jax.lax.broadcasted_iota(jnp.int32, (block_k,), 0)
         s = jnp.where(pos < valid, s, NEG_INF)

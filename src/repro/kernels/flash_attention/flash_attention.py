@@ -40,10 +40,8 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, *, block_k: int, seq_len: int,
     def body(kb, carry):
         acc, m_prev, l_prev = carry
         k0 = kb * block_k
-        k = pl.load(k_ref, (pl.dslice(k0, block_k), slice(None))
-                    ).astype(jnp.float32)
-        v = pl.load(v_ref, (pl.dslice(k0, block_k), slice(None))
-                    ).astype(jnp.float32)
+        k = k_ref[pl.ds(k0, block_k), :].astype(jnp.float32)
+        v = v_ref[pl.ds(k0, block_k), :].astype(jnp.float32)
         s = q @ k.T                                     # (bq, bk) f32
         k_pos = k0 + jax.lax.broadcasted_iota(jnp.int32, (1, block_k), 1)
         mask = jnp.ones((block_q, block_k), jnp.bool_)

@@ -25,6 +25,7 @@ import numpy as np
 
 from repro import obs
 from repro.serve.store import SnapshotStore
+from repro.utils.jax_compat import F32_DOT
 
 
 @jax.jit
@@ -42,7 +43,8 @@ def _lookup(assign, centroids, cache_ids, cache_delta, ids):
 @jax.jit
 def _margins(assign, centroids, cache_ids, cache_delta, ids, X):
     W = _lookup(assign, centroids, cache_ids, cache_delta, ids)
-    return jnp.einsum("bd,bd->b", W, X.astype(jnp.float32))
+    return jnp.einsum("bd,bd->b", W, X.astype(jnp.float32),
+                      precision=F32_DOT)
 
 
 class Predictor:
