@@ -158,8 +158,9 @@ def _run_single_path(exp: Experiment, seed: Seed, plan: RoutePlan,
         if not isinstance(holdout, FederatedData) or holdout.X.ndim != 3:
             raise ValueError("single-problem holdout must be one (m, n, d) "
                              "FederatedData split")
-        evaluation = eval_mod.evaluate_run(
-            res.W, holdout, get_loss(exp.method.loss), exp.eval.metrics)
+        with tel.span("eval"):
+            evaluation = eval_mod.evaluate_run(
+                res.W, holdout, get_loss(exp.method.loss), exp.eval.metrics)
     return Report(result=res, provenance=_provenance(exp, plan),
                   evaluation=evaluation)
 

@@ -574,7 +574,6 @@ class _BlockLoop:
                 # history analysis can tell a flat-lined row from a real one
                 self.stats.degraded_blocks += 1
                 self.tel.counter("blocks_degraded").inc()
-                self.tel.counter("degraded_metrics_carried").inc()
                 self.tel.event("degraded_metrics_carried", block=b,
                                dual=self._last_metrics[0],
                                primal=self._last_metrics[1],
@@ -682,10 +681,9 @@ def _run_blocks_pipelined(loop: _BlockLoop, rounds: int, overlap: int,
     in_flight: deque = deque()   # (block, ids, sizes, future)
     try:
         for b in range(start, rounds):
-            # queue depths at each launch: how full the pack prefetch and
-            # solved-but-unmerged windows actually ran (pipeline health)
+            # pack queue depth at each launch: how full the pack prefetch
+            # actually ran (pipeline health)
             loop.tel.histogram("pack_queue_depth").observe(len(pack_q))
-            loop.tel.histogram("in_flight_depth").observe(len(in_flight))
             while len(in_flight) > staleness:
                 fb, fids, fsizes, fut = in_flight.popleft()
                 loop.fold(fb, fids, fsizes, fut.result())

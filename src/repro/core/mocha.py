@@ -188,10 +188,11 @@ def _run_mocha(data: FederatedData, reg: Regularizer, cfg: MochaConfig,
 
     ``telemetry`` is an optional ``repro.obs.Telemetry`` (cohort blocks pass
     their solve-worker view; the single path passes the run's main view):
-    the whole run gets a driver span, and the scanned driver additionally
+    the whole run gets a ``mocha.run`` span holding ``mocha.setup`` and one
+    ``mocha.omega_step`` per Omega step; the scanned driver additionally
     records its presample / per-segment dispatch (first dispatch = trace +
-    compile) / host-pull phases.  Telemetry only READS state -- results are
-    bit-identical with it on, off, or absent.
+    compile) / host-pull / replay phases.  Telemetry only READS state --
+    results are bit-identical with it on, off, or absent.
     """
     loss = get_loss(cfg.loss)
     validate_assumption2(cfg.budget)
@@ -202,34 +203,35 @@ def _run_mocha(data: FederatedData, reg: Regularizer, cfg: MochaConfig,
         raise ValueError(
             f"engine {eng.name!r} does not support the scanned driver; "
             "use driver='auto' or 'loop'")
-    # hoist the static per-run SDCA precompute (row-norm table) ONCE: the
-    # data never changes across rounds, and every engine/driver below reads
-    # the same table, which also keeps it bit-identical across engines
-    data = dual_mod.with_xnorm2(data)
-    m = data.m
-    omega = reg.init_omega(m) if omega0 is None else omega0
-    abar, K, q_t = _coupling_terms(reg, omega, cfg.gamma, cfg.per_task_sigma,
-                                   m)
-
-    max_steps = cfg.budget.max_steps(data.n_max)
-    from repro.core.subproblem import resolve_gram
-    gram = resolve_gram(data.d, cfg.gram_max_d)
-    state = eng.setup(data, loss, max_steps, gram=gram)
-    if state0 is not None:
-        state = state0
-    if trace is None:
-        sys_cfg = cfg.systems or SystemsConfig(network=cfg.network)
-        trace = SystemsTrace(m, data.d, sys_cfg)
-
     tel = telemetry if telemetry is not None else obs.NULL_TELEMETRY
-    if tel.enabled:
-        # pure READ of the simulated clock; re-binding to the same shared
-        # trace (the cohort case) is idempotent
-        tel.set_sim_clock(lambda: trace.elapsed_s)
     scanned = cfg.driver != "loop" and eng.supports_scan
     run = _run_scanned if scanned else _run_loop
     with tel.span("mocha.run", rounds=cfg.rounds, engine=eng.name,
                   driver="scan" if scanned else "loop"):
+        with tel.span("mocha.setup"):
+            # hoist the static per-run SDCA precompute (row-norm table)
+            # ONCE: the data never changes across rounds, and every
+            # engine/driver below reads the same table, which also keeps it
+            # bit-identical across engines
+            data = dual_mod.with_xnorm2(data)
+            m = data.m
+            omega = reg.init_omega(m) if omega0 is None else omega0
+            abar, K, q_t = _coupling_terms(reg, omega, cfg.gamma,
+                                           cfg.per_task_sigma, m)
+
+            max_steps = cfg.budget.max_steps(data.n_max)
+            from repro.core.subproblem import resolve_gram
+            gram = resolve_gram(data.d, cfg.gram_max_d)
+            state = eng.setup(data, loss, max_steps, gram=gram)
+            if state0 is not None:
+                state = state0
+            if trace is None:
+                sys_cfg = cfg.systems or SystemsConfig(network=cfg.network)
+                trace = SystemsTrace(m, data.d, sys_cfg)
+        if tel.enabled:
+            # pure READ of the simulated clock; re-binding to the same
+            # shared trace (the cohort case) is idempotent
+            tel.set_sim_clock(lambda: trace.elapsed_s)
         return run(data, reg, cfg, loss, eng, trace, state, omega, abar, K,
                    q_t, max_steps, budget_fn, gram, tel)
 
@@ -264,10 +266,11 @@ def _run_loop(data, reg, cfg, loss, eng, trace, state, omega, abar, K, q_t,
         budgets_log.append(steps_np.astype(np.int64))
 
         if cfg.omega_update_every and (h + 1) % cfg.omega_update_every == 0:
-            W = dual_mod.primal_weights(K, state.v)
-            omega = reg.update_omega(W, omega)
-            abar, K, q_t = _coupling_terms(reg, omega, cfg.gamma,
-                                           cfg.per_task_sigma, m)
+            with tel.span("mocha.omega_step", round=h + 1):
+                W = dual_mod.primal_weights(K, state.v)
+                omega = reg.update_omega(W, omega)
+                abar, K, q_t = _coupling_terms(reg, omega, cfg.gamma,
+                                               cfg.per_task_sigma, m)
             # NOTE: Omega changed => the dual problem changed. v = X alpha is
             # Omega-independent; W(alpha) and the objectives pick up the new K.
 
@@ -358,7 +361,8 @@ def _run_scanned(data, reg, cfg, loss, eng, trace, state, omega, abar, K, q_t,
         # the FIRST dispatch traces + compiles the scan program; later
         # segments replay the jit cache and only pay async enqueue -- the
         # span's `compile` tag is the compile-vs-execute split (execution
-        # itself drains under mocha.host_pull)
+        # itself drains under mocha.host_pull, or earlier wherever an Omega
+        # step waits on the device)
         with tel.span("mocha.scan_dispatch", h0=h0, h_end=h_end,
                       compile=not seg_slices):
             state, rows = _scan_rounds(round_fn, loss, max_steps, gram, data,
@@ -368,48 +372,55 @@ def _run_scanned(data, reg, cfg, loss, eng, trace, state, omega, abar, K, q_t,
                                        jnp.asarray(recs))
         seg_slices.append((h0, h_end, recs, rows))
         if tail_update:
-            W = dual_mod.primal_weights(K, state.v)
-            omega = reg.update_omega(W, omega)
-            abar, K, q_t = _coupling_terms(reg, omega, cfg.gamma,
-                                           cfg.per_task_sigma, m)
-            if record[h_end - 1]:
-                metric_rows[h_end - 1] = _metrics(loss, data, state, abar, K)
+            with tel.span("mocha.omega_step", round=h_end):
+                W = dual_mod.primal_weights(K, state.v)
+                omega = reg.update_omega(W, omega)
+                abar, K, q_t = _coupling_terms(reg, omega, cfg.gamma,
+                                               cfg.per_task_sigma, m)
+                if record[h_end - 1]:
+                    metric_rows[h_end - 1] = _metrics(loss, data, state,
+                                                      abar, K)
         h0 = h_end
 
-    # single host transfer: executed budgets + stacked in-scan metric rows
-    # (np.asarray blocks on async dispatch, so this span is where device
-    # EXECUTION time surfaces -- the other half of the compile/execute split)
+    W = dual_mod.primal_weights(K, state.v)   # queued behind the last scan
+    # the host transfers of the run's outputs: stacked in-scan metric rows,
+    # executed budgets, W.  The first np.asarray blocks on the async
+    # dispatches, so this span is where device EXECUTION still queued
+    # surfaces -- the other half of the compile/execute split
     with tel.span("mocha.host_pull", rounds=rounds):
+        seg_np = [(h0s, recs, np.asarray(rows))
+                  for (h0s, _, recs, rows) in seg_slices]
         executed = np.asarray(budgets_all).astype(np.int64)
+        eager_np = {h: tuple(float(x) for x in row)
+                    for h, row in enumerate(metric_rows) if row is not None}
+        W = np.asarray(W)
+        omega = np.asarray(omega)
+    with tel.span("mocha.replay", rounds=rounds):
         trace.replay(executed)
-    # only THIS run's events: a pre-used trace already holds earlier rounds,
-    # and times() is cumulative over all of them (loop-parity: the loop
-    # records trace.elapsed_s, which also continues the prior clock)
-    times = trace.times()[-rounds:]
-    history: Dict[str, List[float]] = {k: [] for k in HISTORY_KEYS}
-    seg_np = [(h0s, recs, np.asarray(rows))
-              for (h0s, _, recs, rows) in seg_slices]
-    eager_np = {h: tuple(float(x) for x in row)
-                for h, row in enumerate(metric_rows) if row is not None}
-    for h0s, recs, rows in seg_np:
-        for i, rec in enumerate(recs):
-            h = h0s + i
-            if rec:
-                eager_np[h] = tuple(float(x) for x in rows[i])
-    for h in range(rounds):
-        if not record[h]:
-            continue
-        dual_val, primal_val, gap = eager_np[h]
-        history["round"].append(h)
-        history["dual"].append(dual_val)
-        history["primal"].append(primal_val)
-        history["gap"].append(gap)
-        history["time"].append(float(times[h]))
-        history["round_max_steps"].append(int(executed[h].max()))
+        # only THIS run's events: a pre-used trace already holds earlier
+        # rounds, and times() is cumulative over all of them (loop-parity:
+        # the loop records trace.elapsed_s, which also continues the prior
+        # clock)
+        times = trace.times()[-rounds:]
+        history: Dict[str, List[float]] = {k: [] for k in HISTORY_KEYS}
+        for h0s, recs, rows in seg_np:
+            for i, rec in enumerate(recs):
+                h = h0s + i
+                if rec:
+                    eager_np[h] = tuple(float(x) for x in rows[i])
+        for h in range(rounds):
+            if not record[h]:
+                continue
+            dual_val, primal_val, gap = eager_np[h]
+            history["round"].append(h)
+            history["dual"].append(dual_val)
+            history["primal"].append(primal_val)
+            history["gap"].append(gap)
+            history["time"].append(float(times[h]))
+            history["round_max_steps"].append(int(executed[h].max()))
 
-    W = dual_mod.primal_weights(K, state.v)
-    return RunResult(W=np.asarray(W), omega=np.asarray(omega), state=state,
-                     history=history, trace=trace, round_budgets=executed)
+    return RunResult(W=W, omega=omega, state=state, history=history,
+                     trace=trace, round_budgets=executed)
 
 
 def run_cocoa(data: FederatedData, reg: Regularizer, cfg: MochaConfig,

@@ -2,7 +2,7 @@
 
 Instruments the cohort runtime's aggregate behaviour (blocks packed /
 solved / folded, retries, degraded blocks, checkpoint bytes + latency,
-merge-frontier staleness, pipeline queue depths, ``ClusterOmega`` LRU
+merge-frontier staleness, the pack queue depth, ``ClusterOmega`` LRU
 hit rate) without touching any result: instruments only READ state, and
 the whole registry is inert (``NullRegistry``) when telemetry is off.
 

@@ -17,9 +17,9 @@ be called from the caller's thread at any time after construction --
 ``prewarm`` publishes the cold version-0 snapshot up front so predictions
 are available before the first block lands.
 
-Observability through ``repro.obs``: ``serve_snapshot_age_folds`` (gauge,
-set every fold), ``serve_publish_s`` (histogram: snapshot build + swap),
-plus the store's ``serve_swap_latency_s`` and the predictor's
+Observability through ``repro.obs``: the ``serve.publish`` span (snapshot
+build + swap), ``serve_snapshot_age_folds`` (gauge, set every fold), plus
+the store's ``serve_swap_latency_s`` and the predictor's
 ``serve_reads``/``serve_stale_reads`` pair.
 """
 from __future__ import annotations
@@ -34,7 +34,6 @@ from repro.cohort.population import Population
 from repro.core.regularizers import Regularizer
 from repro.serve.predict import Predictor
 from repro.serve.store import ServedSnapshot, SnapshotStore
-from repro.utils.timing import tick
 
 
 class ServeSession:
@@ -55,7 +54,6 @@ class ServeSession:
         self.predictor = Predictor(self.store, telemetry=self.tel)
         self._report_builder = report_builder
         self._age_gauge = self.tel.gauge("serve_snapshot_age_folds")
-        self._publish_s = self.tel.histogram("serve_publish_s")
 
         self._versions = 0  # owner: main
         self._published_fold = -2  # owner: main  (-2 = nothing published)
@@ -73,7 +71,6 @@ class ServeSession:
     # -- write side (training fold thread = the `main` role) ----------------
 
     def _publish(self, folded_through: int) -> None:  # worker: main
-        t0 = tick()
         with self.tel.span("serve.publish", version=self._versions,
                            folded_through=folded_through):
             snap = ServedSnapshot.from_state(
@@ -82,7 +79,6 @@ class ServeSession:
             self.store.publish(snap)
         self._versions += 1
         self._published_fold = folded_through
-        self._publish_s.observe(tick() - t0)
 
     def _after_fold(self, b: int) -> None:  # worker: main
         if (b + 1) % self.publish_every == 0:

@@ -2,12 +2,14 @@
 off-path inertness and on-vs-off bit-identity guarantees, span coverage of
 the faulty overlapped cohort pipeline, and the summarize CLI."""
 import dataclasses
+import glob
 import json
 
 import numpy as np
 import pytest
 
 from repro import obs
+from repro.obs import tracer as tracer_mod
 from repro.cohort import (CohortConfig, FaultConfig, Population,
                           PopulationSpec)
 from repro.cohort.driver import _run_cohort
@@ -233,7 +235,7 @@ def test_cohort_span_coverage_under_faults():
     assert s["blocks_degraded"] == stats.degraded_blocks
     assert s["retries"] == stats.retries
     assert s["blocks_solved"] == cfg.rounds - stats.degraded_blocks
-    # pipeline depth histograms observed once per block
+    # the pack queue depth is observed once per block
     assert s["pack_queue_depth.count"] == cfg.rounds
     assert s["launch_staleness.p99"] <= cfg.staleness
     # worker attribution: pack spans on the pack track, solves on solve
@@ -246,7 +248,8 @@ def test_cohort_span_coverage_under_faults():
 def test_degraded_metrics_carried_emits_event_and_counter():
     """Satellite regression: a degraded block's carried-forward metrics are
     VISIBLE -- one `degraded_metrics_carried` event tagged with the stale
-    values plus a matching counter, so silent staleness cannot recur."""
+    values, counted by `blocks_degraded`, so silent staleness cannot
+    recur."""
     pop = Population(SPEC, seed=0)
     dead = 2
     tel = obs.telemetry()
@@ -254,7 +257,9 @@ def test_degraded_metrics_carried_emits_event_and_counter():
         max_retries=1, degrade=True,
         faults=FaultConfig(solve_fail_blocks=(dead,))), telemetry=tel)
     assert res.fault_stats.degraded_blocks == 1
-    assert obs.metrics_summary(tel)["degraded_metrics_carried"] == 1
+    summary = obs.metrics_summary(tel)
+    assert summary["blocks_degraded"] == 1
+    assert "degraded_metrics_carried" not in summary
     events = [sp for sp in tel.tracer.spans()["main"]
               if sp.name == "degraded_metrics_carried"]
     assert len(events) == 1
@@ -347,3 +352,163 @@ def test_summarize_cli_strict_rejects_malformed(tmp_path, capsys):
     bad.write_text(json.dumps({"traceEvents": [{"ph": "Z"}]}))
     assert summarize_mod.main([str(bad), "--strict"]) == 1
     assert summarize_mod.main([str(bad)]) == 0   # non-strict: warn only
+
+
+# -- the profiler bridge and the jax.trace / jax.compile events -------------
+
+def _host_events(trace_dir, names):
+    """{name -> [(start_ns, end_ns, stats)]} of the host-line events whose
+    name is in ``names``, from the xplane ``jax.profiler`` wrote."""
+    from jax.profiler import ProfileData
+    path, = glob.glob(f"{trace_dir}/**/*.xplane.pb", recursive=True)
+    out = {}
+    for plane in ProfileData.from_file(path).planes:
+        if plane.name != "/host:CPU":
+            continue
+        for line in plane.lines:
+            for ev in line.events:
+                if ev.name in names:
+                    out.setdefault(ev.name, []).append(
+                        (ev.start_ns, ev.end_ns, dict(ev.stats)))
+    return out
+
+
+def test_spans_land_on_the_profiler_host_line(tmp_path):
+    """A recording span and a span nested in it appear in the xplane's host
+    line under their names, nested, with their scalar args as metadata;
+    an instant event is a zero-length annotation inside them."""
+    import jax
+    tel = obs.telemetry()
+    with jax.profiler.trace(str(tmp_path)):
+        with tel.span("obs.outer", block=3):
+            with tel.span("obs.inner", tag="x"):
+                tel.event("obs.mark", n=2)
+    got = _host_events(str(tmp_path), {"obs.outer", "obs.inner", "obs.mark"})
+    (o0, o1, outer), = got["obs.outer"]
+    (i0, i1, inner), = got["obs.inner"]
+    (m0, m1, mark), = got["obs.mark"]
+    assert o0 <= i0 <= m0 <= m1 <= i1 <= o1
+    assert outer == {"block": 3} and inner == {"tag": "x"}
+    assert mark == {"n": 2}
+    # the Chrome-JSON sink still records the same spans
+    assert [sp.name for sp in tel.tracer.spans()["main"]] == [
+        "obs.mark", "obs.inner", "obs.outer"]
+
+
+def _fresh_experiment(telemetry, d):
+    """A single-path experiment at a feature width no other test uses, so
+    its programs trace anew."""
+    from repro.api import Eval, Exec, Experiment, Method, Problem
+    from repro.data.synthetic import tiny_problem
+    train, test = tiny_problem(m=3, n=12, d=d, seed=0)
+    return Experiment(problem=Problem(train=train),
+                      method=Method(regularizers=[REG], rounds=4,
+                                    omega_update_every=2),
+                      exec=Exec(driver="scan", telemetry=telemetry),
+                      eval=Eval(record_every=2, holdout=test))
+
+
+def test_telemetry_off_builds_no_annotation_and_records_nothing(
+        monkeypatch):
+    """Off: no TraceAnnotation is constructed and the jax.monitoring
+    listener appends nothing, although the run traces fresh programs; on,
+    the same counters move (so they do watch the right calls)."""
+    import jax
+    obs.telemetry()                       # the listener is installed
+    built, appended = [], []
+    real = jax.profiler.TraceAnnotation
+    monkeypatch.setattr(jax.profiler, "TraceAnnotation",
+                        lambda *a, **k: built.append(a) or real(*a, **k))
+    real_append = tracer_mod.Tracer._append
+    monkeypatch.setattr(tracer_mod.Tracer, "_append",
+                        lambda self, sp: appended.append(sp.name)
+                        or real_append(self, sp))
+    _fresh_experiment(False, d=13).run(seed=0)
+    assert built == [] and appended == []
+    _fresh_experiment(True, d=14).run(seed=0)
+    assert built and "jax.trace" in appended
+
+
+def test_jax_trace_event_names_the_open_span():
+    """A fresh jitted function called inside a span records exactly one
+    jax.trace event, with span=<that span>; a second call records none,
+    and a trace with no recording span open records nothing."""
+    import jax
+    import jax.numpy as jnp
+    from jax import lax
+    tel = obs.telemetry()
+    # lax primitives only: a jnp operator would trace its own inner jit
+    f = jax.jit(lambda x: lax.add(lax.mul(x, x), x))
+    g = jax.jit(lambda x: lax.sub(x, x))
+    x = jnp.arange(4.0)
+    g(x)                                  # no span open: dropped
+    with tel.span("obs.first"):
+        with tel.span("obs.call"):
+            f(x).block_until_ready()
+    with tel.span("obs.second"):
+        f(x).block_until_ready()
+    traces = [sp for sp in tel.tracer.spans()["main"]
+              if sp.name == "jax.trace"]
+    assert len(traces) == 1
+    assert traces[0].args["span"] == "obs.call"
+    assert tel.tracer.count("jax.trace") == 1
+    compiles = [sp.args["span"] for sp in tel.tracer.spans()["main"]
+                if sp.name == "jax.compile"]
+    if not jax.config.jax_compilation_cache_dir:   # no cache hit possible
+        assert compiles == ["obs.call"]
+
+
+def test_single_path_records_every_phase(tmp_path):
+    """Experiment.run on the scanned single path: one setup / presample /
+    host_pull / replay / eval span per run, a scan_dispatch per segment and
+    an omega_step per Omega step."""
+    from repro.api import Eval, Exec, Experiment, Method, Problem
+    from repro.data.synthetic import tiny_problem
+    train, test = tiny_problem(m=4, n=16, d=5, seed=0)
+    exp = Experiment(problem=Problem(train=train),
+                     method=Method(regularizers=[REG], rounds=10,
+                                   omega_update_every=3),
+                     exec=Exec(driver="scan", trace_dir=str(tmp_path)),
+                     eval=Eval(record_every=2, holdout=test))
+    with open(exp.run(seed=0).provenance["trace_path"]) as fh:
+        wall = [ev for ev in json.load(fh)["traceEvents"]
+                if ev.get("cat") == "wall"]
+    names = [ev["name"] for ev in wall]
+    for name in ("experiment", "mocha.run", "mocha.setup", "mocha.presample",
+                 "mocha.host_pull", "mocha.replay", "eval"):
+        assert names.count(name) == 1, name
+    assert names.count("mocha.scan_dispatch") == 4   # rounds 0-3-6-9-10
+    assert names.count("mocha.omega_step") == 3      # after rounds 3, 6, 9
+    run, = (ev for ev in wall if ev["name"] == "mocha.run")
+    setup, = (ev for ev in wall if ev["name"] == "mocha.setup")
+    assert run["ts"] <= setup["ts"]
+    assert setup["ts"] + setup["dur"] <= run["ts"] + run["dur"]
+
+
+@pytest.mark.parametrize("driver", ["scan", "loop"])
+def test_single_path_bit_identity_telemetry_on_vs_off(driver):
+    """The single path's results with telemetry on equal those with it
+    off, to the bit, on both drivers."""
+    from repro.api import Eval, Exec, Experiment, Method, Problem
+    from repro.data.synthetic import tiny_problem
+    train, test = tiny_problem(m=4, n=16, d=5, seed=1)
+
+    def run(telemetry):
+        return Experiment(
+            problem=Problem(train=train),
+            method=Method(regularizers=[REG], rounds=8,
+                          omega_update_every=3),
+            exec=Exec(driver=driver, telemetry=telemetry),
+            eval=Eval(record_every=1, holdout=test)).run(seed=7)
+
+    plain, traced = run(False), run(True)
+    assert plain.provenance["telemetry"] is None
+    assert traced.provenance["telemetry"] is not None
+    res_p, res_t = plain.result, traced.result
+    np.testing.assert_array_equal(res_p.W, res_t.W)
+    np.testing.assert_array_equal(res_p.omega, res_t.omega)
+    np.testing.assert_array_equal(np.asarray(res_p.state.alpha),
+                                  np.asarray(res_t.state.alpha))
+    np.testing.assert_array_equal(res_p.round_budgets, res_t.round_budgets)
+    assert res_p.history == res_t.history
+    assert plain.evaluation.summary == traced.evaluation.summary
