@@ -454,7 +454,9 @@ class _BlockLoop:
         """
         ids = self.schedule.ids[b]
         penalty, fails, err = 0.0, 0, None
-        with self.tel_pack.span("pack", block=b) as sp:
+        threads = self.packer.threads
+        self.tel_pack.gauge("pack_threads").set(threads)
+        with self.tel_pack.span("pack", block=b, threads=threads) as sp:
             for a in range(self.max_attempts):
                 if self.plan is not None and self.plan.pack_fails(b, a):
                     err = InjectedFault("pack", b, a)
@@ -609,6 +611,11 @@ class _BlockLoop:
         if self.on_fold is not None:
             self.on_fold(b)
 
+    def close(self) -> None:
+        """Release the packer's draw threads once no block packs any more
+        (after the loops return or raise)."""
+        self.packer.close()
+
     def checkpoint_on_failure(self) -> None:  # worker: main
         """Force-save the merge frontier before a failure propagates.
 
@@ -730,8 +737,12 @@ def _run_cohort(pop: Population, reg: Regularizer, cfg: CohortConfig,
     if cfg.staleness < 0:
         raise ValueError(f"need staleness >= 0, got {cfg.staleness}")
     loop = _BlockLoop(pop, reg, cfg, telemetry=telemetry)
-    if cfg.overlap > 1 or cfg.staleness > 0:
-        _run_blocks_pipelined(loop, cfg.rounds, cfg.overlap, cfg.staleness)
-    else:
-        _run_blocks_sequential(loop, cfg.rounds)
+    try:
+        if cfg.overlap > 1 or cfg.staleness > 0:
+            _run_blocks_pipelined(loop, cfg.rounds, cfg.overlap,
+                                  cfg.staleness)
+        else:
+            _run_blocks_sequential(loop, cfg.rounds)
+    finally:
+        loop.close()
     return loop.result()

@@ -102,6 +102,8 @@ class ServeSession:
         except BaseException as e:  # noqa: BLE001 -- re-raised by join()
             self._exc = e
             raise
+        finally:
+            self._loop.close()
 
     def start(self) -> "ServeSession":
         """Launch ``run()`` on a background thread and return immediately;

@@ -142,6 +142,69 @@ def test_cohort_packer_reuses_buffers_without_corruption():
         packer.pack(np.asarray([1, 2]))
 
 
+#: HAR's width and padded point axis, with sizes that differ client to client
+HAR_SPEC = PopulationSpec("t_har", m=5_000, d=561, n_min=210, n_max=306,
+                          clusters=3, n_pad=306)
+
+
+def _serial_pack(pop, ids, n_pad):
+    """The pack law written out: one ``client_block`` per slot, zero tails."""
+    K, d = len(ids), pop.spec.d
+    X = np.zeros((K, n_pad, d), np.float32)
+    y = np.zeros((K, n_pad), np.float32)
+    mask = np.zeros((K, n_pad), np.float32)
+    sizes = np.zeros(K, np.int64)
+    for slot, t in enumerate(ids):
+        blk = pop.client_block(int(t))
+        X[slot, :blk.n], y[slot, :blk.n], mask[slot, :blk.n] = blk.X, blk.y, 1
+        sizes[slot] = blk.n
+    return X, y, mask, sizes
+
+
+def _assert_same_bytes(got, want):
+    got = np.asarray(got)
+    assert got.dtype == want.dtype and got.shape == want.shape
+    assert got.tobytes() == want.tobytes()
+
+
+@pytest.mark.parametrize("threads", [1, 2, 8])
+def test_cohort_packer_bit_identical_at_every_pool_size(monkeypatch,
+                                                        threads):
+    """The threaded draw packs the same bytes as a serial ``client_block``
+    loop, at d=561 and n_pad=306: X, y, mask, xnorm2 and sizes, over two
+    packs on one packer whose second cohort has shorter clients in some
+    slots (so a tail left over from the first pack would show)."""
+    from repro.cohort import packing
+    from repro.core.dual import FederatedData, with_xnorm2
+    monkeypatch.setattr(packing, "_usable_cores", lambda: threads)
+    pop = Population(HAR_SPEC, seed=0)
+    K, n_pad = 12, HAR_SPEC.pad_width
+    ids_a, ids_b = np.arange(K) * 37, np.arange(K) * 41 + 3
+    packer = CohortPacker(pop, K)
+    try:
+        assert packer.threads == threads
+        seen = []
+        for ids in (ids_a, ids_b):
+            data, sizes = packer.pack(ids)
+            X, y, mask, want_sizes = _serial_pack(pop, ids, n_pad)
+            _assert_same_bytes(data.X, X)
+            _assert_same_bytes(data.y, y)
+            _assert_same_bytes(data.mask, mask)
+            _assert_same_bytes(sizes, want_sizes)
+            want_x2 = with_xnorm2(FederatedData(
+                X=jnp.array(X), y=jnp.array(y), mask=jnp.array(mask))).xnorm2
+            _assert_same_bytes(data.xnorm2, np.asarray(want_x2))
+            seen.append(want_sizes)
+        # sizes differ, and some slot holds a shorter client the 2nd time
+        assert len(set(seen[0].tolist())) > 1 and (seen[1] < seen[0]).any()
+        one_shot = pack_cohort(pop, ids_b)
+        for name in ("X", "y", "mask", "xnorm2"):
+            _assert_same_bytes(getattr(one_shot, name),
+                               np.asarray(getattr(data, name)))
+    finally:
+        packer.close()
+
+
 # -- driver -----------------------------------------------------------------
 
 def _small_cfg(**kw):

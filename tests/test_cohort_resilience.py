@@ -2,12 +2,14 @@
 fault injection, retry with graceful degradation, Assumption-2 guarding,
 and bit-identical checkpoint/resume on both block loops."""
 import dataclasses
+import threading
 
 import numpy as np
 import pytest
 
-from repro.cohort import (BlockFailure, CohortConfig, FaultConfig, FaultPlan,
-                          Population, PopulationSpec, run_mocha_cohort)
+from repro.cohort import (BlockFailure, CohortConfig, CohortPacker,
+                          FaultConfig, FaultPlan, InjectedFault, Population,
+                          PopulationSpec, run_mocha_cohort)
 from repro.cohort.resilience import (ASSUMPTION2_MAX_P, backoff_delay,
                                      run_fingerprint)
 from repro.core import BudgetConfig, MochaConfig, Probabilistic
@@ -197,6 +199,98 @@ def test_block_failure_without_degradation_names_the_remedy():
         run_mocha_cohort(pop, REG, _cfg(
             faults=FaultConfig(solve_fail_blocks=(1,))))
     assert (ei.value.block, ei.value.stage) == (1, "solve")
+
+
+def _threads(monkeypatch, k):
+    from repro.cohort import packing
+    monkeypatch.setattr(packing, "_usable_cores", lambda: k)
+
+
+def test_pack_retry_through_the_draw_pool_bit_identical(monkeypatch):
+    """A pack that fails in one draw task and is retried gives the serial
+    pack's federation: the retry overwrites every slot, and the failed
+    call returned only after its other draws finished."""
+    pop = Population(SPEC, seed=0)
+    ids = np.arange(16) * 23
+    _threads(monkeypatch, 1)
+    serial = CohortPacker(pop, 16)
+    want, want_sizes = serial.pack(ids)
+    _threads(monkeypatch, 4)
+    packer = CohortPacker(pop, 16)
+    try:
+        assert packer.threads == 4
+        packer.pack(ids[::-1])                  # stale bytes in every slot
+        real = pop.client_block
+
+        def flaky(t):
+            if t == int(ids[5]):
+                raise InjectedFault("pack", 0, 0)
+            return real(t)
+
+        monkeypatch.setattr(pop, "client_block", flaky)
+        with pytest.raises(InjectedFault):
+            packer.pack(ids)
+        monkeypatch.setattr(pop, "client_block", real)
+        got, sizes = packer.pack(ids)
+    finally:
+        packer.close()
+    np.testing.assert_array_equal(sizes, want_sizes)
+    for name in ("X", "y", "mask", "xnorm2"):
+        assert (np.asarray(getattr(got, name)).tobytes()
+                == np.asarray(getattr(want, name)).tobytes()), name
+
+
+def test_injected_pack_faults_retry_through_the_pool(monkeypatch):
+    """Injected pack faults retried by the block loop give the same
+    federation through the draw pool as a clean serial-pack run."""
+    pop = Population(SPEC, seed=0)
+    _threads(monkeypatch, 1)
+    ref = run_mocha_cohort(pop, REG, _cfg())
+    _threads(monkeypatch, 4)
+    faults = FaultConfig(pack_fail_prob=0.4, seed=2)
+    res = run_mocha_cohort(pop, REG, _cfg(max_retries=3, faults=faults))
+    assert res.fault_stats.retries > 0
+    for key in ref.history:
+        if key != "time":
+            assert res.history[key] == ref.history[key], key
+    np.testing.assert_array_equal(res.centroids, ref.centroids)
+    np.testing.assert_array_equal(res.assign, ref.assign)
+
+
+@pytest.mark.parametrize("threads", [1, 4])
+def test_oversized_client_raises_the_same_error(monkeypatch, threads):
+    """A client with n_t > n_pad raises pack's ValueError, whether the
+    draws ran serially or on the pool."""
+    _threads(monkeypatch, threads)
+    pop = Population(SPEC, seed=0)
+    sizes = pop.client_sizes(np.arange(16))
+    n_pad = int(np.sort(sizes)[-2]) - 1       # two clients do not fit
+    big = int(np.flatnonzero(sizes > n_pad)[0])
+    packer = CohortPacker(pop, 16, n_pad)
+    try:
+        with pytest.raises(ValueError, match=(
+                f"client {big} has n_t={sizes[big]} > n_pad={n_pad}; "
+                r"raise PopulationSpec.n_pad \(cohort shapes are static")):
+            packer.pack(np.arange(16))
+    finally:
+        packer.close()
+
+
+def test_packers_release_their_draw_threads(monkeypatch):
+    """Opening, using and closing many packers (and whole runs, which
+    close theirs) leaves the process's thread count where it started."""
+    _threads(monkeypatch, 4)
+    pop = Population(SPEC, seed=0)
+    run_mocha_cohort(pop, REG, _cfg(rounds=1))     # warm JAX's own threads
+    before = threading.active_count()
+    for _ in range(12):
+        packer = CohortPacker(pop, 16)
+        packer.pack(np.arange(16))
+        packer.close()
+        packer.close()                              # idempotent
+    for overlap in (1, 2):
+        run_mocha_cohort(pop, REG, _cfg(rounds=2, overlap=overlap))
+    assert threading.active_count() == before
 
 
 # -- checkpoint / resume ----------------------------------------------------

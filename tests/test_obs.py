@@ -245,6 +245,30 @@ def test_cohort_span_coverage_under_faults():
     assert "fold" in {sp.name for sp in spans["main"]}
 
 
+@pytest.mark.parametrize("threads", [1, 4])
+def test_pack_spans_carry_the_draw_pool_size(monkeypatch, threads):
+    """Each `pack` span says how many workers drew its block and the
+    `pack_threads` gauge holds it; off, the run is bit-identical and
+    records nothing."""
+    from repro.cohort import packing
+    monkeypatch.setattr(packing, "_usable_cores", lambda: threads)
+    pop = Population(SPEC, seed=0)
+    tel = obs.telemetry()
+    traced = _run_cohort(pop, REG, _cfg(), telemetry=tel)
+    packs = [sp for sp in tel.tracer.spans()["pack"] if sp.name == "pack"]
+    assert len(packs) == 6
+    assert all(sp.args["threads"] == threads for sp in packs)
+    assert obs.metrics_summary(tel)["pack_threads.last"] == threads
+    appended = []
+    monkeypatch.setattr(tracer_mod.Tracer, "_append",
+                        lambda self, sp: appended.append(sp.name))
+    plain = _run_cohort(pop, REG, _cfg())
+    assert appended == []
+    assert plain.history == traced.history
+    np.testing.assert_array_equal(plain.centroids, traced.centroids)
+    np.testing.assert_array_equal(plain.assign, traced.assign)
+
+
 def test_degraded_metrics_carried_emits_event_and_counter():
     """Satellite regression: a degraded block's carried-forward metrics are
     VISIBLE -- one `degraded_metrics_carried` event tagged with the stale
