@@ -188,11 +188,16 @@ def _run_mocha(data: FederatedData, reg: Regularizer, cfg: MochaConfig,
 
     ``telemetry`` is an optional ``repro.obs.Telemetry`` (cohort blocks pass
     their solve-worker view; the single path passes the run's main view):
-    the whole run gets a ``mocha.run`` span holding ``mocha.setup`` and one
-    ``mocha.omega_step`` per Omega step; the scanned driver additionally
-    records its presample / per-segment dispatch (first dispatch = trace +
-    compile) / host-pull / replay phases.  Telemetry only READS state --
-    results are bit-identical with it on, off, or absent.
+    the whole run gets a ``mocha.run`` span holding ``mocha.setup`` (tagged
+    with the SDCA loop's plan: ``residual_mode``, ``chunk``, ``max_steps``,
+    ``n_chunks``) and one ``mocha.omega_step`` per Omega step; the scanned
+    driver additionally records its presample / per-segment dispatch (first
+    dispatch = trace + compile) / host-pull / replay phases.  Once the run's
+    outputs are on the host, both drivers count its coordinate steps:
+    ``sdca.steps_required`` (the executed budgets' sum) and
+    ``sdca.steps_lockstep`` (the trips the vmapped loop ran, masked ones
+    included).  Telemetry only READS state -- results are bit-identical
+    with it on, off, or absent.
     """
     loss = get_loss(cfg.loss)
     validate_assumption2(cfg.budget)
@@ -206,9 +211,17 @@ def _run_mocha(data: FederatedData, reg: Regularizer, cfg: MochaConfig,
     tel = telemetry if telemetry is not None else obs.NULL_TELEMETRY
     scanned = cfg.driver != "loop" and eng.supports_scan
     run = _run_scanned if scanned else _run_loop
+    from repro.core.subproblem import _solver_plan, n_chunks, resolve_gram
+    max_steps = cfg.budget.max_steps(data.n_max)
+    gram = resolve_gram(data.d, cfg.gram_max_d)
+    # the SDCA loop's static plan as every engine derives it
+    gram_mode, chunk = _solver_plan(data.d, max_steps, gram)
+    chunks = n_chunks(max_steps, chunk)
     with tel.span("mocha.run", rounds=cfg.rounds, engine=eng.name,
                   driver="scan" if scanned else "loop"):
-        with tel.span("mocha.setup"):
+        with tel.span("mocha.setup",
+                      residual_mode="gram" if gram_mode else "carry",
+                      chunk=chunk, max_steps=max_steps, n_chunks=chunks):
             # hoist the static per-run SDCA precompute (row-norm table)
             # ONCE: the data never changes across rounds, and every
             # engine/driver below reads the same table, which also keeps it
@@ -218,10 +231,6 @@ def _run_mocha(data: FederatedData, reg: Regularizer, cfg: MochaConfig,
             omega = reg.init_omega(m) if omega0 is None else omega0
             abar, K, q_t = _coupling_terms(reg, omega, cfg.gamma,
                                            cfg.per_task_sigma, m)
-
-            max_steps = cfg.budget.max_steps(data.n_max)
-            from repro.core.subproblem import resolve_gram
-            gram = resolve_gram(data.d, cfg.gram_max_d)
             state = eng.setup(data, loss, max_steps, gram=gram)
             if state0 is not None:
                 state = state0
@@ -232,8 +241,16 @@ def _run_mocha(data: FederatedData, reg: Regularizer, cfg: MochaConfig,
             # pure READ of the simulated clock; re-binding to the same
             # shared trace (the cohort case) is idempotent
             tel.set_sim_clock(lambda: trace.elapsed_s)
-        return run(data, reg, cfg, loss, eng, trace, state, omega, abar, K,
-                   q_t, max_steps, budget_fn, gram, tel)
+        res = run(data, reg, cfg, loss, eng, trace, state, omega, abar, K,
+                  q_t, max_steps, budget_fn, gram, tel)
+        if tel.enabled:
+            # host values only: both drivers return the executed budget
+            # matrix already pulled (after mocha.host_pull when scanned)
+            tel.counter("sdca.steps_required").inc(
+                int(res.round_budgets.sum()))
+            tel.counter("sdca.steps_lockstep").inc(
+                cfg.rounds * m * chunks * chunk)
+        return res
 
 
 def _run_loop(data, reg, cfg, loss, eng, trace, state, omega, abar, K, q_t,

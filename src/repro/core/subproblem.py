@@ -130,6 +130,13 @@ def _solver_plan(d: int, max_steps: int,
     return gram, max(1, min(C, max_steps))
 
 
+def n_chunks(max_steps: int, C: int) -> int:
+    """Chunks of ``C`` that a stream of ``max_steps`` draws is laid out in
+    (``chunk_idx_stream``): each task runs ``n_chunks * C`` lockstep trips
+    a round, those past its budget masked."""
+    return -(-max_steps // C)
+
+
 class ChunkPlan(NamedTuple):
     """Chunk layout of a drawn coordinate stream (shared across variants).
 
@@ -157,10 +164,10 @@ def chunk_idx_stream(idx: Array, max_steps: int, C: int) -> Array:
     padded-tail-is-dead invariant (pad coordinate 0 at positions
     >= max_steps >= clamped budget) cannot drift between them.  Accepts a
     (max_steps,) stream or a batched (m, max_steps) stack."""
-    n_chunks = -(-max_steps // C)
-    pad = n_chunks * C - max_steps
+    chunks = n_chunks(max_steps, C)
+    pad = chunks * C - max_steps
     widths = [(0, 0)] * (idx.ndim - 1) + [(0, pad)]
-    return jnp.pad(idx, widths).reshape(idx.shape[:-1] + (n_chunks, C))
+    return jnp.pad(idx, widths).reshape(idx.shape[:-1] + (chunks, C))
 
 
 def _chunk_layout(idx: Array, n: int, max_steps: int, C: int) -> ChunkPlan:

@@ -536,3 +536,47 @@ def test_single_path_bit_identity_telemetry_on_vs_off(driver):
     np.testing.assert_array_equal(res_p.round_budgets, res_t.round_budgets)
     assert res_p.history == res_t.history
     assert plain.evaluation.summary == traced.evaluation.summary
+
+
+@pytest.mark.parametrize("driver", ["scan", "loop"])
+@pytest.mark.parametrize("mode,d", [("gram", 12), ("carry", 136)])
+def test_setup_args_and_sdca_counters_follow_the_solver_plan(tmp_path, mode,
+                                                             d, driver):
+    """``mocha.setup`` carries the SDCA loop's plan as ``_solver_plan``
+    gives it; ``sdca.steps_required`` is the executed budgets' sum and
+    ``sdca.steps_lockstep`` rounds x m x n_chunks x C, on either driver; W
+    is the same to the bit with telemetry off.  36 training points per task
+    are no multiple of either chunk, so the lockstep count includes a
+    padded chunk."""
+    from repro.api import Exec, Experiment, Method, Problem
+    from repro.core.subproblem import _solver_plan, chunk_idx_stream
+    from repro.data.synthetic import tiny_problem
+    m, rounds, budget = 3, 5, BudgetConfig(passes=1.0)
+    train, _ = tiny_problem(m=m, n=48, d=d, seed=3)
+    steps = budget.max_steps(train.n_max)
+
+    def run(**exec_kw):
+        return Experiment(
+            problem=Problem(train=train),
+            method=Method(regularizers=[REG], rounds=rounds,
+                          omega_update_every=2, budget=budget),
+            exec=Exec(driver=driver, **exec_kw)).run(seed=4)
+
+    traced, plain = run(trace_dir=str(tmp_path)), run()
+    gram, C = _solver_plan(d, steps, None)
+    n_chunks = chunk_idx_stream(np.zeros(steps, np.int32), steps, C).shape[0]
+    assert steps == 36 and n_chunks * C > steps
+    assert ("gram" if gram else "carry") == mode
+    assert traced.provenance["gram_mode"] == mode
+    with open(traced.provenance["trace_path"]) as fh:
+        setup, = (ev for ev in json.load(fh)["traceEvents"]
+                  if ev.get("cat") == "wall" and ev["name"] == "mocha.setup")
+    assert setup["args"] == {"residual_mode": mode, "chunk": C,
+                             "max_steps": steps, "n_chunks": n_chunks}
+    counters = traced.provenance["telemetry"]
+    assert counters["sdca.steps_required"] == int(
+        traced.result.round_budgets.sum())
+    assert counters["sdca.steps_lockstep"] == rounds * m * n_chunks * C
+    np.testing.assert_array_equal(plain.result.W, traced.result.W)
+    np.testing.assert_array_equal(plain.result.round_budgets,
+                                  traced.result.round_budgets)
