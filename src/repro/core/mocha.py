@@ -133,6 +133,29 @@ def _coupling_terms(reg: Regularizer, omega: Array, gamma: float,
     return abar, K, q_t
 
 
+#: the set-up's coupling terms as one compiled program (every run, and every
+#: cohort block, pays it); ``core/sweep.py`` traces ``_coupling_terms`` itself
+_coupling = partial(jax.jit, static_argnums=(0, 2, 3, 4))(_coupling_terms)
+
+
+@partial(jax.jit, static_argnums=(0, 1, 2, 3))
+def _omega_step(reg, loss, gamma, per_task_sigma, data, state, K, omega):
+    """One Omega refresh as one compiled program, shared by both drivers.
+
+    W(alpha) under the old K, the central ``update_omega``, the new coupling
+    terms, and the (dual, primal, gap) row of the refresh round under the
+    POST-update K.  Dispatched eagerly, its ~55 ops (``eigh``, ``inv``, ...)
+    would each be a launch of their own, with the device idle in between;
+    compiled, the step is one.  Returns ``(omega, abar, K, q_t, row)``.
+    """
+    W = dual_mod.primal_weights(K, state.v)
+    omega = reg.update_omega(W, omega)
+    abar, K, q_t = _coupling_terms(reg, omega, gamma, per_task_sigma,
+                                   omega.shape[0])
+    row = jnp.stack(_metrics_impl(loss, data, state, abar, K))
+    return omega, abar, K, q_t, row
+
+
 def run_mocha(data: FederatedData, reg: Regularizer, cfg: MochaConfig,
               omega0: Optional[Array] = None,
               budget_fn: Optional[Callable[[Array, Array, int], Array]] = None,
@@ -190,7 +213,8 @@ def _run_mocha(data: FederatedData, reg: Regularizer, cfg: MochaConfig,
     their solve-worker view; the single path passes the run's main view):
     the whole run gets a ``mocha.run`` span holding ``mocha.setup`` (tagged
     with the SDCA loop's plan: ``residual_mode``, ``chunk``, ``max_steps``,
-    ``n_chunks``) and one ``mocha.omega_step`` per Omega step; the scanned
+    ``n_chunks``) and one ``mocha.omega_step`` per Omega step (one launch
+    of ``_omega_step``; ``compile`` is true on the run's first); the scanned
     driver additionally records its presample / per-segment dispatch (first
     dispatch = trace + compile) / host-pull / replay phases.  Once the run's
     outputs are on the host, both drivers count its coordinate steps:
@@ -229,8 +253,8 @@ def _run_mocha(data: FederatedData, reg: Regularizer, cfg: MochaConfig,
             data = dual_mod.with_xnorm2(data)
             m = data.m
             omega = reg.init_omega(m) if omega0 is None else omega0
-            abar, K, q_t = _coupling_terms(reg, omega, cfg.gamma,
-                                           cfg.per_task_sigma, m)
+            abar, K, q_t = _coupling(reg, omega, cfg.gamma,
+                                     cfg.per_task_sigma, m)
             state = eng.setup(data, loss, max_steps, gram=gram)
             if state0 is not None:
                 state = state0
@@ -257,7 +281,6 @@ def _run_loop(data, reg, cfg, loss, eng, trace, state, omega, abar, K, q_t,
               max_steps, budget_fn, gram=None,
               tel=obs.NULL_TELEMETRY) -> RunResult:
     """Python round loop: one engine dispatch + one host sync per round."""
-    m = data.m
     key = jax.random.PRNGKey(cfg.seed)
     record = _record_rounds(cfg.rounds, cfg.record_every)
     history: Dict[str, List[float]] = {k: [] for k in HISTORY_KEYS}
@@ -282,17 +305,20 @@ def _run_loop(data, reg, cfg, loss, eng, trace, state, omega, abar, K, q_t,
         trace.commit(steps_np)
         budgets_log.append(steps_np.astype(np.int64))
 
+        row = None
         if cfg.omega_update_every and (h + 1) % cfg.omega_update_every == 0:
-            with tel.span("mocha.omega_step", round=h + 1):
-                W = dual_mod.primal_weights(K, state.v)
-                omega = reg.update_omega(W, omega)
-                abar, K, q_t = _coupling_terms(reg, omega, cfg.gamma,
-                                               cfg.per_task_sigma, m)
+            with tel.span("mocha.omega_step", round=h + 1,
+                          compile=h + 1 == cfg.omega_update_every):
+                omega, abar, K, q_t, row = _omega_step(
+                    reg, loss, cfg.gamma, cfg.per_task_sigma, data, state, K,
+                    omega)
             # NOTE: Omega changed => the dual problem changed. v = X alpha is
             # Omega-independent; W(alpha) and the objectives pick up the new K.
 
         if record[h]:
-            dual_val, primal_val, gap = _metrics(loss, data, state, abar, K)
+            if row is None:
+                row = _metrics(loss, data, state, abar, K)
+            dual_val, primal_val, gap = np.asarray(row)
             history["round"].append(h)
             history["dual"].append(float(dual_val))
             history["primal"].append(float(primal_val))
@@ -344,7 +370,7 @@ def _run_scanned(data, reg, cfg, loss, eng, trace, state, omega, abar, K, q_t,
     is one scan dispatch.  The executed budget matrix is transferred once at
     the end and replayed through the SystemsTrace (DESIGN.md section 6).
     """
-    m, rounds = data.m, cfg.rounds
+    rounds = cfg.rounds
     with tel.span("mocha.presample", rounds=rounds):
         budget_keys, round_keys = round_key_schedule(
             jax.random.PRNGKey(cfg.seed), rounds)
@@ -365,7 +391,7 @@ def _run_scanned(data, reg, cfg, loss, eng, trace, state, omega, abar, K, q_t,
     record = _record_rounds(rounds, cfg.record_every)
     every = cfg.omega_update_every
     round_fn = eng.scan_round_fn()
-    metric_rows: List[Optional[tuple]] = [None] * rounds  # device scalars
+    omega_rows: Dict[int, Array] = {}     # Omega round -> device (3,) row
     seg_slices: List[tuple] = []          # (h0, h_end, recs, device rows)
 
     h0 = 0
@@ -378,8 +404,7 @@ def _run_scanned(data, reg, cfg, loss, eng, trace, state, omega, abar, K, q_t,
         # the FIRST dispatch traces + compiles the scan program; later
         # segments replay the jit cache and only pay async enqueue -- the
         # span's `compile` tag is the compile-vs-execute split (execution
-        # itself drains under mocha.host_pull, or earlier wherever an Omega
-        # step waits on the device)
+        # itself drains under mocha.host_pull)
         with tel.span("mocha.scan_dispatch", h0=h0, h_end=h_end,
                       compile=not seg_slices):
             state, rows = _scan_rounds(round_fn, loss, max_steps, gram, data,
@@ -389,29 +414,27 @@ def _run_scanned(data, reg, cfg, loss, eng, trace, state, omega, abar, K, q_t,
                                        jnp.asarray(recs))
         seg_slices.append((h0, h_end, recs, rows))
         if tail_update:
-            with tel.span("mocha.omega_step", round=h_end):
-                W = dual_mod.primal_weights(K, state.v)
-                omega = reg.update_omega(W, omega)
-                abar, K, q_t = _coupling_terms(reg, omega, cfg.gamma,
-                                               cfg.per_task_sigma, m)
-                if record[h_end - 1]:
-                    metric_rows[h_end - 1] = _metrics(loss, data, state,
-                                                      abar, K)
+            # one launch, queued behind the segment's scan; the first
+            # traces + compiles (the same `compile` split as the dispatch)
+            with tel.span("mocha.omega_step", round=h_end,
+                          compile=h_end == every):
+                omega, abar, K, q_t, row = _omega_step(
+                    reg, loss, cfg.gamma, cfg.per_task_sigma, data, state, K,
+                    omega)
+            if record[h_end - 1]:
+                omega_rows[h_end - 1] = row
         h0 = h_end
 
     W = dual_mod.primal_weights(K, state.v)   # queued behind the last scan
-    # the host transfers of the run's outputs: stacked in-scan metric rows,
-    # executed budgets, W.  The first np.asarray blocks on the async
-    # dispatches, so this span is where device EXECUTION still queued
-    # surfaces -- the other half of the compile/execute split
+    # the one host transfer of the run's outputs: the segments' stacked
+    # metric rows, the Omega rounds' rows, executed budgets, W, Omega.  It
+    # blocks on the async dispatches, so this span is where device
+    # EXECUTION still queued surfaces -- the other half of the
+    # compile/execute split
     with tel.span("mocha.host_pull", rounds=rounds):
-        seg_np = [(h0s, recs, np.asarray(rows))
-                  for (h0s, _, recs, rows) in seg_slices]
-        executed = np.asarray(budgets_all).astype(np.int64)
-        eager_np = {h: tuple(float(x) for x in row)
-                    for h, row in enumerate(metric_rows) if row is not None}
-        W = np.asarray(W)
-        omega = np.asarray(omega)
+        seg_np, omega_np, executed, W, omega = jax.device_get(
+            (seg_slices, omega_rows, budgets_all, W, omega))
+        executed = executed.astype(np.int64)
     with tel.span("mocha.replay", rounds=rounds):
         trace.replay(executed)
         # only THIS run's events: a pre-used trace already holds earlier
@@ -420,15 +443,15 @@ def _run_scanned(data, reg, cfg, loss, eng, trace, state, omega, abar, K, q_t,
         # clock)
         times = trace.times()[-rounds:]
         history: Dict[str, List[float]] = {k: [] for k in HISTORY_KEYS}
-        for h0s, recs, rows in seg_np:
+        rows_np = dict(omega_np)
+        for h0s, _, recs, rows in seg_np:
             for i, rec in enumerate(recs):
-                h = h0s + i
                 if rec:
-                    eager_np[h] = tuple(float(x) for x in rows[i])
+                    rows_np[h0s + i] = rows[i]
         for h in range(rounds):
             if not record[h]:
                 continue
-            dual_val, primal_val, gap = eager_np[h]
+            dual_val, primal_val, gap = (float(x) for x in rows_np[h])
             history["round"].append(h)
             history["dual"].append(dual_val)
             history["primal"].append(primal_val)

@@ -6,9 +6,9 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.core import (BudgetConfig, MeanRegularized, MochaConfig,
-                        Probabilistic, duality_gap, per_task_error, run_cocoa,
-                        run_mocha)
+from repro.core import (BudgetConfig, Clustered, Graphical, MeanRegularized,
+                        MochaConfig, Probabilistic, duality_gap,
+                        per_task_error, run_cocoa, run_mocha)
 from repro.data.synthetic import tiny_problem
 
 
@@ -196,3 +196,42 @@ def test_history_time_axis_monotone(problem):
         record_every=2))
     t = np.asarray(res.history["time"])
     assert np.all(np.diff(t) > 0)
+
+
+@pytest.mark.parametrize("reg,warm", [
+    (MeanRegularized(lambda1=0.5, lambda2=0.5), True),
+    (Clustered(lam=0.5, eta=0.4, k=2), True),
+    (Probabilistic(lam=1e-2, sigma2=10.0), True),
+    (Graphical(lam=0.5, lam2=0.01), True),
+    (Probabilistic(lam=1e-2, sigma2=10.0), False),   # cold start, W = 0
+    (Clustered(lam=0.5, eta=0.4, k=2), False),
+], ids=["mean", "clustered", "probabilistic", "graphical",
+        "probabilistic-cold", "clustered-cold"])
+def test_compiled_omega_step_matches_eager_composition(problem, reg, warm):
+    """The drivers' one-program Omega step computes what its parts compute
+    when dispatched one by one: W, ``update_omega``, the coupling terms and
+    the refresh round's metrics under the new K."""
+    from repro.core import dual as dual_mod
+    from repro.core.losses import get_loss
+    from repro.core.mocha import (_coupling_terms, _metrics_impl,
+                                  _omega_step)
+    train, _ = problem
+    data = dual_mod.with_xnorm2(train)
+    loss, gamma, m = get_loss("hinge"), 0.8, train.m
+    if warm:   # a nonzero iterate, mid-run under the initial Omega
+        state = run_mocha(train, reg, MochaConfig(
+            rounds=4, gamma=gamma, budget=BudgetConfig(passes=1.0))).state
+    else:
+        state = dual_mod.init_state(data)
+    omega0 = reg.init_omega(m)
+    _, K0, _ = _coupling_terms(reg, omega0, gamma, True, m)
+    W = dual_mod.primal_weights(K0, state.v)
+    assert bool(jnp.any(W != 0)) == warm
+    omega = reg.update_omega(W, omega0)
+    abar, K, q_t = _coupling_terms(reg, omega, gamma, True, m)
+    row = jnp.stack(_metrics_impl(loss, data, state, abar, K))
+    got = _omega_step(reg, loss, gamma, True, data, state, K0, omega0)
+    for want, have in zip((omega, abar, K, q_t, row), got):
+        assert have.shape == want.shape and have.dtype == want.dtype
+        np.testing.assert_allclose(np.asarray(have), np.asarray(want),
+                                   rtol=1e-6)

@@ -510,6 +510,33 @@ def test_single_path_records_every_phase(tmp_path):
 
 
 @pytest.mark.parametrize("driver", ["scan", "loop"])
+def test_omega_step_compiles_once_per_shape(tmp_path, driver):
+    """Each Omega step is one compiled program: only a run's first
+    ``mocha.omega_step`` carries ``compile=True``, and a second run of the
+    same Experiment traces nothing under any of its steps."""
+    from repro.api import Exec, Experiment, Method, Problem
+    from repro.data.synthetic import tiny_problem
+    train, _ = tiny_problem(m=4, n=16, d=5, seed=2)
+    exp = Experiment(problem=Problem(train=train),
+                     method=Method(regularizers=[REG], rounds=9,
+                                   omega_update_every=3),
+                     exec=Exec(driver=driver, trace_dir=str(tmp_path)))
+
+    def wall_events(seed):
+        with open(exp.run(seed=seed).provenance["trace_path"]) as fh:
+            return [ev for ev in json.load(fh)["traceEvents"]
+                    if ev.get("cat") == "wall"]
+
+    first, second = wall_events(0), wall_events(1)
+    for wall in (first, second):
+        steps = [ev["args"] for ev in wall if ev["name"] == "mocha.omega_step"]
+        assert [a["round"] for a in steps] == [3, 6, 9]
+        assert [a["compile"] for a in steps] == [True, False, False]
+    assert not [ev for ev in second if ev["name"] == "jax.trace"
+                and ev["args"]["span"] == "mocha.omega_step"]
+
+
+@pytest.mark.parametrize("driver", ["scan", "loop"])
 def test_single_path_bit_identity_telemetry_on_vs_off(driver):
     """The single path's results with telemetry on equal those with it
     off, to the bit, on both drivers."""
