@@ -9,10 +9,9 @@ import jax.numpy as jnp
 import numpy as np
 
 import repro.api as api
-from repro.api.compat import experiment_from_mocha
 from repro.core import (BudgetConfig, MeanRegularized, MiniBatchConfig,
-                        MochaConfig, Probabilistic, per_task_error,
-                        run_mb_sdca, run_mb_sgd, stack_federations)
+                        Probabilistic, per_task_error, run_mb_sdca,
+                        run_mb_sgd, stack_federations)
 from repro.core import systems_model
 from repro.data import synthetic as syn
 # the sanctioned (result, elapsed) wrapper, re-exported for the suite
@@ -26,7 +25,7 @@ from repro.utils.timing import timed  # noqa: F401
 #   evaluation instead of 5-fold CV (CPU budget); same model classes.
 # --full restores the paper's protocol (10 shuffles, wider lambda grid) --
 # feasible because model_comparison dispatches the whole grid through the
-# vmapped sweep harness (core/sweep.py) instead of sequential run_mocha calls.
+# vmapped sweep harness (core/sweep.py) instead of sequential core-driver runs.
 SHUFFLES = 3
 LAMBDAS = (1e-3, 1e-2, 1e-1)
 SHUFFLES_FULL = 10
@@ -44,18 +43,20 @@ def _error(train, test, W) -> float:
                                           test.y, test.mask)))
 
 
-def _kind_setup(kind: str, lam: float, rounds: int):
-    """(regularizer, MochaConfig) for one Table-1/4 model kind."""
+def _kind_setup(kind: str, lam: float, rounds: int) -> api.Method:
+    """The ``api.Method`` of one Table-1/4 model kind."""
     budget = BudgetConfig(passes=1.0)
     if kind in ("global", "local"):
-        return (MeanRegularized(lambda1=0.0, lambda2=lam),
-                MochaConfig(loss="hinge", rounds=rounds, budget=budget,
-                            record_every=rounds))
+        return api.Method(loss="hinge",
+                          regularizers=MeanRegularized(lambda1=0.0,
+                                                       lambda2=lam),
+                          rounds=rounds, budget=budget)
     if kind == "mtl":
-        return (Probabilistic(lam=lam, sigma2=10.0),
-                MochaConfig(loss="hinge", rounds=rounds,
-                            omega_update_every=max(5, rounds // 5),
-                            budget=budget, record_every=rounds))
+        return api.Method(loss="hinge",
+                          regularizers=Probabilistic(lam=lam, sigma2=10.0),
+                          rounds=rounds,
+                          omega_update_every=max(5, rounds // 5),
+                          budget=budget)
     raise ValueError(kind)
 
 
@@ -65,29 +66,22 @@ def _kind_split(kind: str, train, test):
     return train, test
 
 
-def run_single(train, reg, cfg: MochaConfig, budget_fn=None,
-               trace=None) -> api.Report:
-    """One core-driver run through the experiment surface.
-
-    The benchmark-side bridge from a ``MochaConfig`` description to
-    ``repro.api`` (the legacy ``run_mocha`` shim would emit a
-    DeprecationWarning from first-party code -- the CI quickstart gate's
-    whole point)."""
-    exp = experiment_from_mocha(train, reg, cfg, budget_fn=budget_fn,
-                                trace=trace)
-    return exp.run(cfg.seed)
+def run_single(train, method: api.Method, *, seed: int = 0,
+               record_every: int = 1, systems: api.Systems = api.Systems(),
+               driver: str = "auto") -> api.Report:
+    """One core-driver run of ``method`` through the experiment surface."""
+    return api.Experiment(
+        problem=api.Problem(train=train), method=method, systems=systems,
+        exec=api.Exec(driver=driver),
+        eval=api.Eval(record_every=record_every)).run(seed)
 
 
-def _grid_experiment(train_s, regs, cfg: MochaConfig,
+def _grid_experiment(train_s, method: api.Method,
                      test_s) -> api.Experiment:
     """(shuffle x lambda) grid + held-out eval as ONE experiment spec."""
     return api.Experiment(
-        problem=api.Problem(train=train_s),
-        method=api.Method(loss=cfg.loss, regularizers=tuple(regs),
-                          rounds=cfg.rounds,
-                          omega_update_every=cfg.omega_update_every,
-                          budget=cfg.budget),
-        eval=api.Eval(record_every=cfg.record_every, holdout=test_s))
+        problem=api.Problem(train=train_s), method=method,
+        eval=api.Eval(record_every=method.rounds, holdout=test_s))
 
 
 def fit_eval(kind: str, train, test, lam: float, rounds: int) -> float:
@@ -96,10 +90,10 @@ def fit_eval(kind: str, train, test, lam: float, rounds: int) -> float:
     Single-cell convenience wrapper over the experiment surface; grids
     should call ``model_comparison`` (one batched dispatch per kind).
     """
-    reg, cfg = _kind_setup(kind, lam, rounds)
+    method = _kind_setup(kind, lam, rounds)
     train, test = _kind_split(kind, train, test)
-    report = _grid_experiment(stack_federations([train]), [reg], cfg,
-                              stack_federations([test])).run(cfg.seed)
+    report = _grid_experiment(stack_federations([train]), method,
+                              stack_federations([test])).run(0)
     return float(report.evaluation.grid[0, 0])
 
 
@@ -110,10 +104,10 @@ def fit_eval_sequential(kind: str, train, test, lam: float,
     Kept as the wall-clock baseline the sweep harness is measured against
     (BENCH_table1.json) and as an independent cross-check of sweep results.
     """
-    reg, cfg = _kind_setup(kind, lam, rounds)
+    method = _kind_setup(kind, lam, rounds)
     train, test = _kind_split(kind, train, test)
-    res = run_single(train, reg,
-                     dataclasses.replace(cfg, driver="loop")).result
+    res = run_single(train, method, driver="loop",
+                     record_every=rounds).result
     return _error(train, test, res.W)
 
 
@@ -127,7 +121,7 @@ def model_comparison(spec, rounds: int = 60, shuffles: int = SHUFFLES,
     lambda is chosen by held-out error from the Report's eval table, then
     mean/stderr aggregate over shuffles (EXPERIMENTS.md).  The returned dict
     carries the last Report's provenance under ``"_provenance"`` so suite
-    rows can record the routed driver / resolved gram crossover.
+    rows can record the routed driver / residual mode.
     """
     feds = [syn.make_federation(spec, seed=seed) for seed in range(shuffles)]
     out: Dict[str, Dict[str, float]] = {}
@@ -136,9 +130,11 @@ def model_comparison(spec, rounds: int = 60, shuffles: int = SHUFFLES,
         splits = [_kind_split(kind, tr, te) for tr, te in feds]
         train_s = stack_federations([tr for tr, _ in splits])
         test_s = stack_federations([te for _, te in splits])
-        _, cfg = _kind_setup(kind, lambdas[0], rounds)
-        regs = [_kind_setup(kind, lam, rounds)[0] for lam in lambdas]
-        report = _grid_experiment(train_s, regs, cfg, test_s).run(cfg.seed)
+        regs = tuple(_kind_setup(kind, lam, rounds).regularizers[0]
+                     for lam in lambdas)
+        method = dataclasses.replace(_kind_setup(kind, lambdas[0], rounds),
+                                     regularizers=regs)
+        report = _grid_experiment(train_s, method, test_s).run(0)
         errs = report.evaluation.grid           # (lambda, shuffle)
         best = errs.min(axis=0)                 # best lambda per shuffle
         out[kind] = {"mean": float(best.mean()),
@@ -152,7 +148,7 @@ def model_comparison_sequential(spec, rounds: int = 60,
                                 shuffles: int = SHUFFLES,
                                 lambdas: Sequence[float] = LAMBDAS,
                                 ) -> Dict[str, Dict[str, float]]:
-    """The pre-sweep Table-1/4 path: sequential run_mocha per grid cell."""
+    """The pre-sweep Table-1/4 path: one core-driver run per grid cell."""
     out: Dict[str, List[float]] = {"global": [], "local": [], "mtl": []}
     for seed in range(shuffles):
         train, test = syn.make_federation(spec, seed=seed)
@@ -167,9 +163,9 @@ def model_comparison_sequential(spec, rounds: int = 60,
 
 def primal_star(train, reg, rounds: int = 400) -> float:
     """High-accuracy optimum for suboptimality curves."""
-    res = run_single(train, reg, MochaConfig(
-        loss="hinge", rounds=rounds, budget=BudgetConfig(passes=3.0),
-        record_every=rounds))
+    res = run_single(train, api.Method(regularizers=reg, rounds=rounds,
+                                       budget=BudgetConfig(passes=3.0)),
+                     record_every=rounds)
     return res.final("primal")
 
 
@@ -347,10 +343,10 @@ def run_method_trajectories(train, reg, rounds: int, seed: int = 0,
                 caps = jnp.maximum((caps * frac).astype(jnp.int32), 1)
             return caps
 
-        report = run_single(train, reg, MochaConfig(
-            loss="hinge", rounds=rounds * 3,
-            budget=BudgetConfig(passes=16.0), seed=seed, record_every=1),
-            budget_fn=budget_fn)
+        report = run_single(
+            train, api.Method(regularizers=reg, rounds=rounds * 3,
+                              budget=BudgetConfig(passes=16.0),
+                              budget_fn=budget_fn), seed=seed)
         trajs["_provenance"] = report.provenance
         res = report.result
         # clock cycle consistent with this variant's deadline: budgets were

@@ -4,16 +4,17 @@ from __future__ import annotations
 
 import numpy as np
 
+import repro.api as api
 from benchmarks import common
-from repro.core import BudgetConfig, MeanRegularized, MochaConfig
+from repro.core import BudgetConfig, MeanRegularized
 from repro.data.synthetic import tiny_problem
 
 
 def _rate(loss: str, rounds: int):
     train, _ = tiny_problem(m=6, n=40, d=10, seed=0)
-    res = common.run_single(train, MeanRegularized(0.5, 0.5), MochaConfig(
-        loss=loss, rounds=rounds, budget=BudgetConfig(passes=1.0),
-        record_every=1))
+    res = common.run_single(train, api.Method(
+        loss=loss, regularizers=MeanRegularized(0.5, 0.5), rounds=rounds,
+        budget=BudgetConfig(passes=1.0)))
     dual = np.asarray(res.history["dual"])
     sub = dual - dual[-1]
     keep = sub > 1e-4

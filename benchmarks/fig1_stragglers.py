@@ -15,9 +15,10 @@ driver-level controller path (budgets capped by ``trace.begin_round()``).
 """
 from __future__ import annotations
 
+import repro.api as api
 from benchmarks import common
-from repro.core import (BudgetConfig, MeanRegularized, MochaConfig,
-                        SystemsConfig, systems_model)
+from repro.core import (BudgetConfig, MeanRegularized, SystemsConfig,
+                        systems_model)
 from repro.data import synthetic as syn
 
 EPS = 1e-2
@@ -33,11 +34,11 @@ def semi_sync_end_to_end(train, reg, rounds: int, network: str,
     cycle_s = (common.MOCHA_DEADLINES[-1] * n_mean
                * systems_model.SDCA_STEP_FLOPS(train.d)
                / systems_model.CLOCK_FLOPS)
-    res = common.run_single(train, reg, MochaConfig(
-        loss="hinge", rounds=rounds * 3, budget=BudgetConfig(passes=16.0),
-        systems=SystemsConfig(network=network, policy="semi_sync",
-                              clock_cycle_s=cycle_s),
-        record_every=1))
+    res = common.run_single(
+        train, api.Method(regularizers=reg, rounds=rounds * 3,
+                          budget=BudgetConfig(passes=16.0)),
+        systems=api.Systems(config=SystemsConfig(
+            network=network, policy="semi_sync", clock_cycle_s=cycle_s)))
     return common.time_to_epsilon(res.history, p_star, EPS)
 
 

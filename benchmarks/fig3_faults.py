@@ -3,8 +3,9 @@ MOCHA converges for p < 1 (Assumption 2); a permanently dead node (p == 1)
 converges to the wrong solution (the paper's green dotted line)."""
 from __future__ import annotations
 
+import repro.api as api
 from benchmarks import common
-from repro.core import (BudgetConfig, MeanRegularized, MochaConfig)
+from repro.core import BudgetConfig, MeanRegularized
 from repro.data import synthetic as syn
 import warnings
 
@@ -13,16 +14,16 @@ def run(quick: bool = True):
     train, _ = syn.make_federation(syn.HUMAN_ACTIVITY, seed=0)
     reg = MeanRegularized(lambda1=0.1, lambda2=0.1)
     rounds = 120 if quick else 400
-    ref = common.run_single(train, reg, MochaConfig(
-        loss="hinge", rounds=rounds, budget=BudgetConfig(passes=1.0),
-        record_every=rounds))
+    ref = common.run_single(train, api.Method(
+        regularizers=reg, rounds=rounds, budget=BudgetConfig(passes=1.0)),
+        record_every=rounds)
     p_ref = ref.final("primal")
     rows = []
     for p in (0.0, 0.25, 0.5, 0.75, 0.9):
-        res, us = common.timed(common.run_single, train, reg, MochaConfig(
-            loss="hinge", rounds=rounds,
-            budget=BudgetConfig(passes=1.0, drop_prob=p),
-            record_every=rounds))
+        res, us = common.timed(common.run_single, train, api.Method(
+            regularizers=reg, rounds=rounds,
+            budget=BudgetConfig(passes=1.0, drop_prob=p)),
+            record_every=rounds)
         sim = res.trace.summary()
         rows.append({
             "bench": "fig3", "drop_prob": p, "us_per_call": us,
@@ -37,10 +38,10 @@ def run(quick: bool = True):
     # p == 1 on one node: must NOT converge to the reference solution
     with warnings.catch_warnings():
         warnings.simplefilter("ignore")
-        dead = common.run_single(train, reg, MochaConfig(
-            loss="hinge", rounds=rounds,
-            budget=BudgetConfig(passes=1.0, never_send_node=0),
-            record_every=rounds))
+        dead = common.run_single(train, api.Method(
+            regularizers=reg, rounds=rounds,
+            budget=BudgetConfig(passes=1.0, never_send_node=0)),
+            record_every=rounds)
     rows.append({
         "bench": "fig3", "drop_prob": 1.0,
         "provenance": dead.provenance,

@@ -100,8 +100,7 @@ def main() -> None:
         # every BENCH row carries the shared provenance schema: rows that ran
         # through the experiment router recorded their own block (routed
         # driver, config hash); everything else gets the ambient one (the
-        # resolved gram crossover + backend), replacing per-suite ad-hoc
-        # plumbing of individual fields
+        # backend), replacing per-suite ad-hoc plumbing of individual fields
         from repro.api import base_provenance
         ambient = base_provenance()
         for row in rows:

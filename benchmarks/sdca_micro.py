@@ -240,9 +240,6 @@ def run(quick: bool = True) -> List[Dict]:
             row = {
                 "bench": "sdca", "shape": tag, "variant": variant,
                 "m": m, "n": n, "d": d, "steps": steps, "C": C,
-                # the crossover in effect (REPRO_GRAM_MAX_D-overridable) now
-                # rides in the shared provenance block benchmarks/run.py
-                # attaches to every row
                 "us_per_call": t * 1e6,
                 "us_per_step": t * 1e6 / steps,
                 "speedup_vs_v1": speedup,

@@ -16,10 +16,9 @@ The capability router picks the fastest applicable execution path (vmapped
 sweep / device-resident scan / Python loop / cohort blocks) and falls back
 sequentially -- with the reason recorded in ``report.provenance`` -- where
 a batched path does not apply.  ``report`` carries history, trace, held-out
-eval tables, and provenance (engine, driver, resolved gram crossover,
-config hash).  DESIGN.md section 8 documents the routing rules and the
-Report schema; the legacy entry points (``run_mocha`` & co.) remain as
-deprecated shims over this surface.
+eval tables, and provenance (engine, driver, SDCA residual mode, config
+hash).  DESIGN.md section 8 documents the routing rules and the Report
+schema.
 """
 from repro.api.execute import (base_provenance, run_experiment,
                                serve_experiment)

@@ -4,9 +4,9 @@
 re-implements an execution path: the single/scanned/loop paths are the core
 driver (``repro.core.mocha``), the batched grid is the vmapped sweep
 (``repro.core.sweep``), the cross-device path is the cohort block loop
-(``repro.cohort.driver``).  What lives here is the glue the legacy entry
-points each hand-rolled: seed normalization, the sequential grid fallback,
-held-out evaluation, and the provenance block.
+(``repro.cohort.driver``).  What lives here is the glue around them: seed
+normalization, the sequential grid fallback, held-out evaluation, and the
+provenance block.
 """
 from __future__ import annotations
 
@@ -34,15 +34,12 @@ Seed = Union[int, Sequence[int]]
 
 def base_provenance() -> Dict[str, Any]:
     """The ambient provenance block for work that ran OUTSIDE the router
-    (micro-benchmarks, raw solver calls): resolved crossover + backend, with
-    the router fields explicitly empty.  Benchmark rows default to this so
-    every BENCH_*.json row carries the same schema."""
+    (micro-benchmarks, raw solver calls): the backend, with the router
+    fields explicitly empty.  Benchmark rows default to this so every
+    BENCH_*.json row carries the same schema."""
     import jax
-
-    from repro.core.subproblem import active_gram_max_d
     return {"path": None, "driver": None, "engine": None,
-            "fallback_reason": None, "gram_max_d": int(active_gram_max_d()),
-            "gram_mode": None, "config_hash": None,
+            "fallback_reason": None, "gram_mode": None, "config_hash": None,
             "backend": jax.default_backend(),
             "retries": None, "degraded_blocks": None,
             "telemetry": None, "trace_path": None}
@@ -51,16 +48,15 @@ def base_provenance() -> Dict[str, Any]:
 def _provenance(exp: Experiment, plan: RoutePlan) -> Dict[str, Any]:
     import jax
 
-    from repro.core.subproblem import active_gram_max_d
-    resolved = (exp.exec.gram_max_d if exp.exec.gram_max_d is not None
-                else active_gram_max_d())
+    from repro.core.subproblem import _solver_plan
+    # the residual mode depends on d alone; the step count only sizes chunks
+    gram, _ = _solver_plan(exp.problem.d, max_steps=1)
     return {
         "path": plan.path,
         "driver": plan.driver,
         "engine": plan.engine,
         "fallback_reason": plan.reason,
-        "gram_max_d": int(resolved),
-        "gram_mode": "gram" if exp.problem.d <= int(resolved) else "carry",
+        "gram_mode": "gram" if gram else "carry",
         "config_hash": config_fingerprint(exp),
         "backend": jax.default_backend(),
         # fault accounting: only the cohort path retries/degrades; its

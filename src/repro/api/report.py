@@ -2,10 +2,9 @@
 
 Every execution path -- single run, vmapped sweep, sequential grid, cohort
 block loop -- returns the SAME container, so benchmark suites and callers
-stop switching on which legacy entry point produced a result.  The
-path-specific payload (``RunResult`` / ``SweepResult`` /
-``CohortRunResult``) stays reachable as ``result`` (the legacy shims unwrap
-it for back-compat), while the cross-path views -- ``history``, ``trace``,
+never switch on which path produced a result.  The path-specific payload
+(``RunResult`` / ``SweepResult`` / ``CohortRunResult``) stays reachable as
+``result``, while the cross-path views -- ``history``, ``trace``,
 ``evaluation``, ``provenance`` -- are uniform.
 """
 from __future__ import annotations
@@ -21,8 +20,8 @@ from repro.core.evaluate import EvalReport
 #: telemetry/trace_path are the observability block (flat metrics summary
 #: and Chrome-trace artifact path, None unless Exec.telemetry/trace_dir)
 PROVENANCE_KEYS = ("path", "driver", "engine", "fallback_reason",
-                   "gram_max_d", "gram_mode", "config_hash", "backend",
-                   "retries", "degraded_blocks", "telemetry", "trace_path")
+                   "gram_mode", "config_hash", "backend", "retries",
+                   "degraded_blocks", "telemetry", "trace_path")
 
 
 @dataclasses.dataclass
@@ -31,9 +30,9 @@ class Report:
 
     ``provenance`` records how the run actually executed: the router's
     chosen ``path`` and inner ``driver``, the resolved ``engine``, the
-    ``fallback_reason`` (None when a batched path served), the RESOLVED
-    ``gram_max_d`` crossover with the resulting ``gram_mode``, the spec
-    ``config_hash``, and the jax ``backend``.
+    ``fallback_reason`` (None when a batched path served), the SDCA
+    ``gram_mode`` the problem's d selects, the spec ``config_hash``, and the
+    jax ``backend``.
     """
 
     result: Any                            # RunResult | SweepResult | CohortRunResult

@@ -1,8 +1,6 @@
 """Capability router: (Problem axes x Exec engine x Systems policy) -> path.
 
-Replaces the scattered ``ValueError`` walls the legacy entry points grew
-(``run_sweep`` rejecting non-local engines and semi_sync clocks) with
-explicit routing: when the batched path does not apply, the experiment
+Routing is explicit: when the batched path does not apply, the experiment
 FALLS BACK to an equivalent sequential path and the reason is logged and
 recorded in ``Report.provenance`` -- a lambda-grid sweep on the sharded
 engine *works* today and silently speeds up when a batched path later
@@ -50,7 +48,7 @@ def batch_incompatibility(exp: Experiment, engine) -> Optional[str]:
     """Why the vmapped sweep cannot serve this grid (None = it can).
 
     Ordered from substrate to statistics so the recorded reason names the
-    FIRST wall, matching how the legacy entry points used to raise.
+    FIRST wall.
     """
     from repro.core.sweep import grid_batch_reason
     if engine.name != "local":

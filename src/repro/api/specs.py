@@ -1,9 +1,6 @@
 """Declarative experiment specs: ONE surface for every MOCHA scenario.
 
-The repo grew four diverging entry points (``run_mocha`` / ``run_sweep`` /
-``run_mocha_cohort`` / ``run_mocha_distributed``), each with its own config
-dataclass and override kwargs.  ``Experiment`` replaces them with a single
-description composed of five orthogonal sub-specs:
+``Experiment`` describes a run as five orthogonal sub-specs:
 
   * ``Problem``  -- WHAT is solved: one cross-silo federation, a stack of
                     shuffles (grid axis), or a streaming client population;
@@ -11,18 +8,17 @@ description composed of five orthogonal sub-specs:
                     them), round/budget/omega schedules, warm starts;
   * ``Systems``  -- the simulated systems environment: network, clock policy,
                     participation sampling, fault injection;
-  * ``Exec``     -- HOW it executes: engine, driver, residual-mode crossover,
-                    mesh/wire dtype, cohort and cache sizes;
+  * ``Exec``     -- HOW it executes: engine, driver, mesh/wire dtype, cohort
+                    and cache sizes;
   * ``Eval``     -- what is measured: metric set, cadence, the per-client
                     held-out split / held-out-client count.
 
 ``Experiment.run(seed)`` routes through the capability router
 (repro.api.router) to the scanned/vmapped/loop/cohort execution paths and
-returns a unified ``Report``.  ``MochaConfig`` / ``CohortConfig`` are no
-longer authored by hand inside drivers: ``as_mocha_config`` /
-``as_cohort_config`` rebuild them as thin frozen views over the sub-specs
-(this is what killed the old ``_INNER_PASSTHROUGH`` field mirror in
-repro.cohort.driver).  See DESIGN.md section 8.
+returns a unified ``Report``.  Each knob has its one home here:
+``as_mocha_config`` / ``as_cohort_config`` build the core drivers' internal
+views (``MochaConfig`` / ``CohortConfig``) from the sub-specs, the one
+mapping between this surface and the drivers.  See DESIGN.md section 8.
 """
 from __future__ import annotations
 
@@ -189,8 +185,7 @@ class Exec:
 
     ``engine`` accepts a name, ``RoundEngine`` class, or configured
     instance; ``mesh`` / ``comm_dtype`` configure the sharded runtime when
-    ``engine='sharded'``.  ``gram_max_d`` overrides the SDCA residual-mode
-    crossover per run (DESIGN.md section 3a).  The cohort block is sized by
+    ``engine='sharded'``.  The cohort block is sized by
     ``cohort`` / ``inner_rounds`` / ``clusters`` / ``eta`` /
     ``cache_clients`` / ``n_pad`` and pipelined by ``overlap`` /
     ``staleness`` (population problems only).
@@ -198,7 +193,6 @@ class Exec:
 
     engine: Any = "local"              # local | pallas | sharded | instance
     driver: str = "auto"               # auto | scan | loop
-    gram_max_d: Optional[int] = None
     mesh: Any = None                   # sharded: explicit device mesh
     comm_dtype: Any = None             # sharded: wire dtype for Delta v
     state0: Optional[DualState] = None  # warm-start dual iterate
@@ -357,7 +351,7 @@ class Experiment:
 
 
 # ---------------------------------------------------------------------------
-# Config views: the legacy dataclasses, derived from the specs in ONE place
+# Config views: the core drivers' settings, derived from the specs in ONE place
 # ---------------------------------------------------------------------------
 
 
@@ -381,7 +375,6 @@ def as_mocha_config(exp: Experiment, seed: int = 0, *,
         record_every=(exp.eval.record_every if record_every is None
                       else record_every),
         driver=exp.exec.driver,
-        gram_max_d=exp.exec.gram_max_d,
     )
 
 
@@ -389,8 +382,7 @@ def as_cohort_config(exp: Experiment, seed: int = 0):
     """``CohortConfig`` as a thin frozen view over the sub-specs.
 
     The inner per-block solver settings are themselves a ``MochaConfig``
-    view (``CohortConfig.inner``), which is what removed the old
-    ``_INNER_PASSTHROUGH`` field mirror."""
+    view (``CohortConfig.inner``)."""
     from repro.cohort.driver import CohortConfig
     inner = dataclasses.replace(as_mocha_config(exp, seed=seed), systems=None)
     return CohortConfig(

@@ -5,7 +5,7 @@ relationship factorization -- at O(m + k^2) memory; everything at and below
 the round is the unchanged cross-silo machinery (DESIGN.md section 7).
 """
 from repro.cohort.driver import (COHORT_HISTORY_KEYS, CohortConfig,
-                                 CohortRunResult, run_mocha_cohort)
+                                 CohortRunResult)
 from repro.cohort.omega import ClusterOmega, StalenessBoundedMerger
 from repro.cohort.packing import CohortPacker, pack_cohort
 from repro.cohort.resilience import (BlockFailure, CohortCheckpointer,

@@ -1,7 +1,7 @@
-"""``run_mocha_cohort``: cross-device MOCHA over a streaming population.
+"""``_run_cohort``: cross-device MOCHA over a streaming population.
 
 One outer round (a *block*) is: sample a cohort of K clients from the
-population, pack it as an m=K federation, and run ``run_mocha`` on it --
+population, pack it as an m=K federation, and run ``_run_mocha`` on it --
 the SAME driver, engines, budget controller, and systems clock as the
 cross-silo path -- warm-started from the factored global state and with the
 cohort's expanded K x K relationship block as its (fixed) Omega.  The
@@ -10,7 +10,7 @@ the next block is sampled.
 
 What stays device-resident / bounded:
 
-  * the inner W-round loop runs on ``run_mocha``'s scanned driver whenever
+  * the inner W-round loop runs on ``_run_mocha``'s scanned driver whenever
     the engine supports it (selection, drops, and budgets are all
     pre-sampled, so each block is one ``lax.scan`` program reused across
     blocks -- shapes are static by construction: K and ``n_pad`` never
@@ -43,7 +43,7 @@ Two block loops share the machinery above (``_BlockLoop``):
 With K = m, a uniform sampler, no dropout, and omega refreshes off, every
 block is exactly one full-participation MOCHA round over the (permuted)
 population with the equivalent fixed Omega -- the cohort driver degrades to
-plain ``run_mocha`` (the parity test in tests/test_cohort.py).
+plain ``_run_mocha`` (the parity test in tests/test_cohort.py).
 
 Both loops are FAULT-TOLERANT through ``repro.cohort.resilience``: the
 pack and solve stages run behind retry-with-backoff wrappers
@@ -92,14 +92,13 @@ COHORT_HISTORY_KEYS = HISTORY_KEYS + ("unique_clients",)
 
 @dataclasses.dataclass(frozen=True)
 class CohortConfig:
-    """Cross-device run description: outer-loop knobs + an INNER MochaConfig.
+    """The cohort driver's internal view: outer-loop knobs + an INNER
+    ``MochaConfig``.
 
-    The inner per-block solver settings (loss, budgets, gamma, engine, gram
-    crossover, ...) are a plain ``MochaConfig`` view under ``inner`` -- no
-    mirrored field list to keep in sync (the old ``_INNER_PASSTHROUGH``
-    wiring point is gone); ``repro.api.as_cohort_config`` builds both layers
-    from one set of sub-specs.  ``inner.rounds`` / ``inner.record_every`` /
-    ``inner.omega_update_every`` / ``inner.seed`` are owned by the block
+    Built from the ``repro.api`` specs by ``repro.api.as_cohort_config``,
+    which also builds the per-block solver settings (loss, budgets, gamma,
+    engine, ...) under ``inner``.  ``inner.rounds`` / ``inner.record_every``
+    / ``inner.omega_update_every`` / ``inner.seed`` are owned by the block
     loop (``inner_config`` overrides them), everything else passes through.
     """
 
@@ -188,41 +187,6 @@ def _block_seed(seed: int, block: int) -> int:
     """Deterministic per-block inner-driver seed (domain-separated)."""
     ss = np.random.SeedSequence([_BLOCK_STREAM, seed, block])
     return int(ss.generate_state(1, np.uint32)[0])
-
-
-def run_mocha_cohort(pop: Population, reg: Regularizer,
-                     cfg: CohortConfig) -> CohortRunResult:
-    """Deprecated shim: construct a ``repro.api.Experiment`` instead
-    (``Problem(population=...)`` + the cohort knobs on ``Exec``/``Systems``).
-
-    Bit-parity-tested against ``Experiment.run`` in tests/test_api.py.
-    """
-    from repro.api import Eval, Exec, Experiment, Method, Problem, Systems
-    from repro.api.compat import warn_legacy
-    warn_legacy("run_mocha_cohort()",
-                "Problem(population=...), Exec(cohort=..., clusters=...)")
-    exp = Experiment(
-        problem=Problem(population=pop),
-        method=Method(loss=cfg.inner.loss, regularizers=(reg,),
-                      rounds=cfg.rounds,
-                      omega_update_every=cfg.omega_update_every,
-                      gamma=cfg.inner.gamma,
-                      per_task_sigma=cfg.inner.per_task_sigma,
-                      budget=cfg.inner.budget),
-        systems=Systems(network=cfg.network, config=cfg.systems,
-                        sampler=cfg.sampler, dropout=cfg.dropout,
-                        faults=cfg.faults),
-        exec=Exec(engine=cfg.inner.engine, driver=cfg.inner.driver,
-                  gram_max_d=cfg.inner.gram_max_d, cohort=cfg.cohort,
-                  inner_rounds=cfg.inner_rounds, clusters=cfg.clusters,
-                  eta=cfg.eta, cache_clients=cfg.cache_clients,
-                  n_pad=cfg.n_pad, overlap=cfg.overlap,
-                  staleness=cfg.staleness, max_retries=cfg.max_retries,
-                  degrade=cfg.degrade, checkpoint_every=cfg.checkpoint_every,
-                  checkpoint_dir=cfg.checkpoint_dir, resume=cfg.resume,
-                  telemetry=cfg.telemetry, trace_dir=cfg.trace_dir),
-        eval=Eval(record_every=cfg.record_every))
-    return exp.run(cfg.seed).result
 
 
 @dataclasses.dataclass
@@ -724,7 +688,7 @@ def _run_cohort(pop: Population, reg: Regularizer, cfg: CohortConfig,
 
     ``reg`` plays its usual two roles, both in cohort/cluster space: its
     ``coupling`` turns the expanded K x K Omega block into the subproblem
-    coupling inside each ``run_mocha`` call, and its ``update_omega`` is
+    coupling inside each ``_run_mocha`` call, and its ``update_omega`` is
     the central Omega step applied to the (k, d) centroid matrix every
     ``omega_update_every`` blocks.
 

@@ -18,7 +18,7 @@ so a cohort of K clients sees the K x K coupling
 -- clients relate through their clusters, plus ``eta`` self-affinity that
 keeps per-client freedom (and the expansion full-rank).  The m x m matrix
 this implicitly defines is never materialized; only cohort-sized blocks
-are, which is what lets the unchanged ``run_mocha`` engines execute them.
+are, which is what lets the unchanged ``_run_mocha`` engines execute them.
 
 Updates are incremental from cohort statistics only: participated clients
 are re-assigned to the nearest warm centroid, centroids track a running

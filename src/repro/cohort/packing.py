@@ -2,7 +2,7 @@
 
 The whole point of the cohort subsystem is that everything below the
 sampler is UNCHANGED: a packed cohort is a perfectly ordinary m=K
-federation, so ``run_mocha`` and all three round engines (local vmap /
+federation, so ``_run_mocha`` and all three round engines (local vmap /
 pallas kernel / shard_map) execute it as-is.  Sharding consequently
 distributes the K-task cohort over the mesh -- never the population
 (``federated.sharding.pad_tasks`` pads the cohort's task axis to the shard

@@ -31,7 +31,7 @@ class FederatedData(NamedTuple):
     """Padded per-task data for an m-node federated MTL problem.
 
     ``xnorm2`` is the per-run precomputed ``||x_t^i||^2`` table the SDCA
-    inner loop needs every round -- ``run_mocha`` fills it once per run via
+    inner loop needs every round -- ``_run_mocha`` fills it once per run via
     ``with_xnorm2`` (the data is static, so recomputing it per round was
     pure waste); ``None`` means "not precomputed" and solvers fall back to
     computing it on the fly with the same pinned formula

@@ -18,9 +18,10 @@ mathematical round onto a different execution substrate:
                          communication).
 
 Contract: ``setup(data, loss, max_steps, gram=None)`` returns the initial
-real-size ``DualState`` (``gram`` is the optional residual-mode override the
-driver resolves from ``MochaConfig.gram_max_d``; every engine must thread it
-to its solver so a re-tuned crossover stays engine-consistent);
+real-size ``DualState`` (``gram`` forces a residual mode for tests that
+compare the two modes at one shape; the driver never sets it, leaving the
+mode to ``subproblem._solver_plan``.  Every engine threads it to its solver
+so a forced mode stays engine-consistent);
 ``round(state, K, q_t, budgets, gamma, key)`` returns the
 updated real-size state.  Engines may keep padded / device-resident internals,
 but the driver only ever sees (m, n_max) / (m, d) arrays, so metrics and the
@@ -61,8 +62,8 @@ class RoundEngine(abc.ABC):
     def scan_round_fn(self):
         """Pure round function for the scanned driver, called as
         ``fn(loss, max_steps, gram, data, state, K, q_t, budgets, gamma,
-        key)`` (``gram`` = the setup-time residual-mode override, a static
-        argument like ``loss``/``max_steps``).
+        key)`` (``gram`` = a forced residual mode, a static argument like
+        ``loss``/``max_steps``; the scanned driver passes None).
 
         Must be a stable module-level callable (it is a static jit argument)
         whose results are bit-identical to ``round``.  Only meaningful when

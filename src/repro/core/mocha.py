@@ -61,6 +61,13 @@ DRIVERS = ("auto", "scan", "loop")
 
 @dataclasses.dataclass(frozen=True)
 class MochaConfig:
+    """The core driver's internal view of one run's settings.
+
+    Built from the ``repro.api`` specs by ``repro.api.as_mocha_config`` --
+    the one mapping from the declarative surface to the driver; user code
+    describes runs with ``repro.api.Experiment``.
+    """
+
     loss: str = "hinge"
     rounds: int = 100                  # total federated W rounds
     omega_update_every: int = 0        # 0 = fixed Omega; k = update every k rounds
@@ -73,12 +80,6 @@ class MochaConfig:
     seed: int = 0
     record_every: int = 1
     driver: str = "auto"               # auto | scan | loop (DESIGN.md section 6)
-    #: per-run override of the SDCA residual-mode crossover (DESIGN.md
-    #: section 3a): d <= gram_max_d selects gram mode.  None defers to the
-    #: process default (``REPRO_GRAM_MAX_D`` env var, else the CPU-measured
-    #: constant in core/subproblem.py).  Forcing carry below the default
-    #: crossover leaves the cross-engine bit-parity contract.
-    gram_max_d: Optional[int] = None
 
 
 @dataclasses.dataclass
@@ -156,29 +157,6 @@ def _omega_step(reg, loss, gamma, per_task_sigma, data, state, K, omega):
     return omega, abar, K, q_t, row
 
 
-def run_mocha(data: FederatedData, reg: Regularizer, cfg: MochaConfig,
-              omega0: Optional[Array] = None,
-              budget_fn: Optional[Callable[[Array, Array, int], Array]] = None,
-              engine: Optional[RoundEngine] = None,
-              trace: Optional[SystemsTrace] = None,
-              state0: Optional[DualState] = None,
-              ) -> RunResult:
-    """Deprecated shim: construct a ``repro.api.Experiment`` instead.
-
-    Kept for back-compat (bit-parity-tested against ``Experiment.run`` in
-    tests/test_api.py); the override kwargs map onto the spec fields --
-    ``omega0``/``budget_fn`` -> ``Method``, ``trace`` -> ``Systems``,
-    ``engine``/``state0`` -> ``Exec``.
-    """
-    from repro.api.compat import experiment_from_mocha, warn_legacy
-    warn_legacy("run_mocha()",
-                "Problem(train=...), Method(...), Exec(engine=...)")
-    exp = experiment_from_mocha(data, reg, cfg, omega0=omega0,
-                                budget_fn=budget_fn, engine=engine,
-                                trace=trace, state0=state0)
-    return exp.run(cfg.seed).result
-
-
 def _run_mocha(data: FederatedData, reg: Regularizer, cfg: MochaConfig,
                omega0: Optional[Array] = None,
                budget_fn: Optional[Callable[[Array, Array, int],
@@ -192,7 +170,7 @@ def _run_mocha(data: FederatedData, reg: Regularizer, cfg: MochaConfig,
 
     This is the internal single-run implementation every execution path of
     ``repro.api`` bottoms out in; user code enters through
-    ``repro.api.Experiment`` (or the deprecated ``run_mocha`` shim above).
+    ``repro.api.Experiment``.
 
     ``budget_fn(key, n_t, round) -> (m,) int budgets`` overrides the
     BudgetConfig sampler (used by benchmark harnesses).  ``engine`` overrides
@@ -235,11 +213,10 @@ def _run_mocha(data: FederatedData, reg: Regularizer, cfg: MochaConfig,
     tel = telemetry if telemetry is not None else obs.NULL_TELEMETRY
     scanned = cfg.driver != "loop" and eng.supports_scan
     run = _run_scanned if scanned else _run_loop
-    from repro.core.subproblem import _solver_plan, n_chunks, resolve_gram
+    from repro.core.subproblem import _solver_plan, n_chunks
     max_steps = cfg.budget.max_steps(data.n_max)
-    gram = resolve_gram(data.d, cfg.gram_max_d)
     # the SDCA loop's static plan as every engine derives it
-    gram_mode, chunk = _solver_plan(data.d, max_steps, gram)
+    gram_mode, chunk = _solver_plan(data.d, max_steps)
     chunks = n_chunks(max_steps, chunk)
     with tel.span("mocha.run", rounds=cfg.rounds, engine=eng.name,
                   driver="scan" if scanned else "loop"):
@@ -255,7 +232,7 @@ def _run_mocha(data: FederatedData, reg: Regularizer, cfg: MochaConfig,
             omega = reg.init_omega(m) if omega0 is None else omega0
             abar, K, q_t = _coupling(reg, omega, cfg.gamma,
                                      cfg.per_task_sigma, m)
-            state = eng.setup(data, loss, max_steps, gram=gram)
+            state = eng.setup(data, loss, max_steps)
             if state0 is not None:
                 state = state0
             if trace is None:
@@ -266,7 +243,7 @@ def _run_mocha(data: FederatedData, reg: Regularizer, cfg: MochaConfig,
             # shared trace (the cohort case) is idempotent
             tel.set_sim_clock(lambda: trace.elapsed_s)
         res = run(data, reg, cfg, loss, eng, trace, state, omega, abar, K,
-                  q_t, max_steps, budget_fn, gram, tel)
+                  q_t, max_steps, budget_fn, tel)
         if tel.enabled:
             # host values only: both drivers return the executed budget
             # matrix already pulled (after mocha.host_pull when scanned)
@@ -278,8 +255,7 @@ def _run_mocha(data: FederatedData, reg: Regularizer, cfg: MochaConfig,
 
 
 def _run_loop(data, reg, cfg, loss, eng, trace, state, omega, abar, K, q_t,
-              max_steps, budget_fn, gram=None,
-              tel=obs.NULL_TELEMETRY) -> RunResult:
+              max_steps, budget_fn, tel=obs.NULL_TELEMETRY) -> RunResult:
     """Python round loop: one engine dispatch + one host sync per round."""
     key = jax.random.PRNGKey(cfg.seed)
     record = _record_rounds(cfg.rounds, cfg.record_every)
@@ -332,9 +308,9 @@ def _run_loop(data, reg, cfg, loss, eng, trace, state, omega, abar, K, q_t,
                      round_budgets=np.stack(budgets_log))
 
 
-@partial(jax.jit, static_argnums=(0, 1, 2, 3))
-def _scan_rounds(round_fn, loss, max_steps, gram, data, state, K, abar, q_t,
-                 gamma, keys, budgets, recs):
+@partial(jax.jit, static_argnums=(0, 1, 2))
+def _scan_rounds(round_fn, loss, max_steps, data, state, K, abar, q_t, gamma,
+                 keys, budgets, recs):
     """One device-resident segment of W-rounds (constant Omega/K).
 
     Scans the engine's pure round function (``RoundEngine.scan_round_fn``, a
@@ -342,12 +318,13 @@ def _scan_rounds(round_fn, loss, max_steps, gram, data, state, K, abar, q_t,
     (per-round key, budgets, record flag) rows; metrics are computed in-scan
     only on record rounds (``lax.cond`` skips the objective evaluation
     otherwise), so the stacked (rounds, 3) metric rows are the only
-    per-round output.
+    per-round output.  The round function's ``gram`` slot gets None: the
+    driver leaves the residual mode to ``_solver_plan``.
     """
 
     def body(st, xs):
         k_round, b, rec = xs
-        st = round_fn(loss, max_steps, gram, data, st, K, q_t, b, gamma,
+        st = round_fn(loss, max_steps, None, data, st, K, q_t, b, gamma,
                       k_round)
         row = jax.lax.cond(
             rec,
@@ -360,8 +337,7 @@ def _scan_rounds(round_fn, loss, max_steps, gram, data, state, K, abar, q_t,
 
 
 def _run_scanned(data, reg, cfg, loss, eng, trace, state, omega, abar, K, q_t,
-                 max_steps, budget_fn, gram=None,
-                 tel=obs.NULL_TELEMETRY) -> RunResult:
+                 max_steps, budget_fn, tel=obs.NULL_TELEMETRY) -> RunResult:
     """Device-resident driver: the W-round loop runs inside ``lax.scan``.
 
     Budgets (and semi_sync deadline caps) are round-indexed, so the whole
@@ -407,7 +383,7 @@ def _run_scanned(data, reg, cfg, loss, eng, trace, state, omega, abar, K, q_t,
         # itself drains under mocha.host_pull)
         with tel.span("mocha.scan_dispatch", h0=h0, h_end=h_end,
                       compile=not seg_slices):
-            state, rows = _scan_rounds(round_fn, loss, max_steps, gram, data,
+            state, rows = _scan_rounds(round_fn, loss, max_steps, data,
                                        state, K, abar, q_t, cfg.gamma,
                                        round_keys[h0:h_end],
                                        budgets_all[h0:h_end],

@@ -43,7 +43,6 @@ bit-identical under it (tests/test_runtime.py).
 """
 from __future__ import annotations
 
-import os
 from functools import partial
 from typing import NamedTuple, Optional, Tuple
 
@@ -80,35 +79,12 @@ _GRAM_CHUNK = 32
 _CARRY_CHUNK_WIDE = 64     # d >= _CARRY_WIDE_D
 _CARRY_CHUNK_NARROW = 16
 _CARRY_WIDE_D = 512
-#: static feature-count crossover for the default residual mode: the Gram
-#: path trades the per-step O(d) reduction for C*d GEMM FLOPs per step,
-#: which pays off when d is small relative to the sequential-step cost
-#: (and on MXU-class hardware generally; measured on CPU in BENCH_sdca).
-#: This is the CPU-measured default; ``REPRO_GRAM_MAX_D`` (env var) or
-#: ``MochaConfig.gram_max_d`` override it for TPU re-tuning.
+#: static feature-count crossover for the residual mode: the Gram path
+#: trades the per-step O(d) reduction for C*d GEMM FLOPs per step, which
+#: pays off when d is small relative to the sequential-step cost (and on
+#: MXU-class hardware generally; measured on CPU in BENCH_sdca).  The one
+#: home of the rule: a re-tune is a measured change to this constant.
 _GRAM_MAX_D = 128
-
-
-def active_gram_max_d() -> int:
-    """The residual-mode crossover in effect: ``REPRO_GRAM_MAX_D`` when set,
-    else the CPU-measured module default.
-
-    Read per call so benchmarks/tests can override it, but the value feeds
-    STATIC solver plans inside jitted programs: set the env var before the
-    first solve of a shape -- changing it mid-process will not retrace
-    already-compiled programs.  ``BENCH_sdca.json`` rows record the active
-    value so re-tuned runs are distinguishable."""
-    return int(os.environ.get("REPRO_GRAM_MAX_D", _GRAM_MAX_D))
-
-
-def resolve_gram(d: int, gram_max_d: Optional[int]) -> Optional[bool]:
-    """Turn a per-run crossover override into the existing ``gram`` knob.
-
-    ``None`` (no override) keeps the shared ``_solver_plan`` default;
-    otherwise the returned bool is threaded through the engines exactly like
-    a forced mode.  NOTE: forcing carry below the default crossover leaves
-    the cross-engine bit-parity contract (see ``_carry_g``)."""
-    return None if gram_max_d is None else d <= int(gram_max_d)
 
 
 def _solver_plan(d: int, max_steps: int,
@@ -117,12 +93,13 @@ def _solver_plan(d: int, max_steps: int,
 
     A pure function of the static problem shape so the jnp solvers, the
     Pallas kernel, and the sharded runtime all agree without plumbing a
-    config knob through the engine contract.  ``gram`` overrides the default
-    rule (benchmarks / tests exercise both modes at every shape;
-    ``MochaConfig.gram_max_d`` resolves to it via ``resolve_gram``).
+    config knob through the engine contract: gram mode iff
+    ``d <= _GRAM_MAX_D``.  ``gram`` forces a mode (tests and the solver
+    micro-benchmark compare both modes at one shape; the driver never sets
+    it).
     """
     if gram is None:
-        gram = d <= active_gram_max_d()
+        gram = d <= _GRAM_MAX_D
     if gram:
         C = _GRAM_CHUNK
     else:
@@ -261,7 +238,7 @@ def row_norms(X: Array) -> Array:
     The inner barrier rounds the squares before the reduce: inside a jit,
     XLA's CPU backend contracts ``x*x`` into the reduce's adds as FMAs,
     which an eager (op-by-op) call cannot, so without it the hoisted
-    per-run table (``run_mocha``, eager) and the in-jit hoist (the vmapped
+    per-run table (``_run_mocha``, eager) and the in-jit hoist (the vmapped
     sweep) differ by a ulp.  The outer barrier materializes the table so
     the reduce cannot be re-fused into a consumer with a context-dependent
     partial-sum tree -- the hoisted table, the in-solver fallback, and the
@@ -422,7 +399,7 @@ def batched_local_sdca(loss: Loss, X: Array, y: Array, mask: Array,
     """vmap of ``local_sdca`` across tasks: (m, n, d), (m, n), ... (m, 2).
 
     ``xnorm2`` (m, n) is the per-run hoisted row-norm table threaded through
-    ``run_mocha`` (recomputed here when absent -- e.g. dry-run lowerings)."""
+    ``_run_mocha`` (recomputed here when absent -- e.g. dry-run lowerings)."""
     if xnorm2 is None:
         xnorm2 = row_norms(X)
     fn = lambda X, y, mask, alpha, w, q, b, k, xn: local_sdca(

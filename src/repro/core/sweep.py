@@ -2,7 +2,7 @@
 
 Table-1/4 style evaluation is a (shuffle x lambda) grid of otherwise
 identical MOCHA runs -- exactly the hyperparameter-tuning workload that
-dominates federated evaluation cost.  ``run_sweep`` executes the whole grid
+dominates federated evaluation cost.  ``_run_sweep`` executes the whole grid
 as ONE batched device program: the scanned driver (core/mocha.py) is vmapped
 over shuffles (data batched, regularizer fixed) and again over the
 regularizer grid (data broadcast, hyperparameters batched), so an R x S grid
@@ -23,7 +23,7 @@ builds a fresh trace from the SAME ``SystemsConfig``, every cell sees the
 same (rounds, m) cap matrix -- so one pre-sampled matrix, folded into the
 pre-sampled budgets exactly as the scanned driver folds it, reproduces the
 fallback cell-for-cell bitwise.  The sweep measures statistics, not time:
-no trace is replayed (run a single ``run_mocha`` for wall-clock curves).
+no trace is replayed (run a single ``_run_mocha`` for wall-clock curves).
 
 Shuffles with different ``n_max`` are right-padded to a common size by
 ``stack_federations``; masks/budgets make padding inert (padded points are
@@ -153,13 +153,10 @@ def _sweep_exec(cfg: MochaConfig, template: Regularizer,
     """
     from repro.core.engine import _local_round
 
-    from repro.core.subproblem import resolve_gram
-
     loss = get_loss(cfg.loss)
     m, n_max = data.X.shape[1], data.X.shape[2]
     max_steps = cfg.budget.max_steps(n_max)
     rounds, every = cfg.rounds, cfg.omega_update_every
-    gram = resolve_gram(data.X.shape[3], cfg.gram_max_d)
 
     def driver(d, pvals, key, caps):
         d = dual_mod.with_xnorm2(d)   # per-cell hoist of the static SDCA
@@ -185,7 +182,7 @@ def _sweep_exec(cfg: MochaConfig, template: Regularizer,
         def body(carry, xs):
             state, omega, abar, K, q_t = carry
             h, k_round, b = xs
-            state = _local_round(loss, max_steps, gram, d, state, K, q_t, b,
+            state = _local_round(loss, max_steps, None, d, state, K, q_t, b,
                                  cfg.gamma, k_round)
             carry = (state, omega, abar, K, q_t)
             if every:   # pred is round-indexed (unbatched), so cond stays lazy
@@ -244,39 +241,6 @@ def _shard_grid(data: FederatedData, params: Tuple[Array, ...], keys: Array,
     return data, params, keys
 
 
-def run_sweep(data: Union[FederatedData, Sequence[FederatedData]],
-              regs: Sequence[Regularizer],
-              seeds: Union[int, Sequence[int]],
-              cfg: MochaConfig) -> SweepResult:
-    """Deprecated shim: construct a ``repro.api.Experiment`` instead.
-
-    NOTE a deliberate capability change relative to the historical entry
-    point: grids this harness used to REJECT (semi_sync clocks, non-local
-    engines, mixed/non-numeric regularizer grids) now complete through the
-    router's sequential fallback, with the reason recorded in
-    ``Report.provenance`` -- only genuinely malformed inputs still raise.
-    """
-    from repro.api import Eval, Exec, Experiment, Method, Problem, Systems
-    from repro.api.compat import warn_legacy
-    warn_legacy("run_sweep()",
-                "Problem(train=[shuffles...]), Method(regularizers=grid)")
-    if isinstance(data, FederatedData) and data.X.ndim != 4:
-        raise ValueError("run_sweep expects stacked (S, m, n, d) data; got "
-                         f"X of shape {data.X.shape}")
-    exp = Experiment(
-        problem=Problem(train=data),
-        method=Method(loss=cfg.loss, regularizers=tuple(regs),
-                      rounds=cfg.rounds,
-                      omega_update_every=cfg.omega_update_every,
-                      gamma=cfg.gamma, per_task_sigma=cfg.per_task_sigma,
-                      budget=cfg.budget),
-        systems=Systems(network=cfg.network, config=cfg.systems),
-        exec=Exec(engine=cfg.engine, driver=cfg.driver,
-                  gram_max_d=cfg.gram_max_d),
-        eval=Eval(record_every=cfg.record_every))
-    return exp.run(seeds).result
-
-
 def _run_sweep(data: Union[FederatedData, Sequence[FederatedData]],
                regs: Sequence[Regularizer],
                seeds: Union[int, Sequence[int]],
@@ -292,16 +256,16 @@ def _run_sweep(data: Union[FederatedData, Sequence[FederatedData]],
     if not isinstance(data, FederatedData):
         data = stack_federations(data)
     if data.X.ndim != 4:
-        raise ValueError("run_sweep expects stacked (S, m, n, d) data; got "
+        raise ValueError("the sweep expects stacked (S, m, n, d) data; got "
                          f"X of shape {data.X.shape}")
     from repro.core.engine import get_engine
     if get_engine(cfg.engine).name != "local":
         raise ValueError(
-            f"run_sweep batches the LocalEngine scanned driver only; "
+            f"the sweep batches the LocalEngine scanned driver only; "
             f"cfg.engine={cfg.engine!r} is not supported")
     validate_assumption2(cfg.budget)
     if not regs:
-        raise ValueError("run_sweep needs at least one regularizer")
+        raise ValueError("the sweep needs at least one regularizer")
 
     n_shuffles = data.X.shape[0]
     if isinstance(seeds, (int, np.integer)):

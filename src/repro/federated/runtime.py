@@ -18,18 +18,14 @@ the same budgets and keys (tested in tests/test_runtime.py).
 from __future__ import annotations
 
 from functools import partial
-from typing import TYPE_CHECKING, Optional, Tuple
-
-if TYPE_CHECKING:   # circular at runtime (core.mocha drives this module)
-    from repro.core.mocha import MochaConfig, RunResult
-    from repro.core.regularizers import Regularizer
+from typing import Tuple
 
 import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding
 from jax.sharding import PartitionSpec as P
 
-from repro.core.dual import DualState, FederatedData
+from repro.core.dual import FederatedData
 from repro.core.losses import Loss
 from repro.core.subproblem import batched_local_sdca
 from repro.utils.jax_compat import F32_DOT
@@ -62,8 +58,8 @@ def distributed_round(mesh: Mesh, loss: Loss, max_steps: int,
         bf16 halves the round's only communicated tensor; the replicated v
         accumulator stays f32 so quantization error does not compound --
         validated in tests/test_runtime.py).
-      gram: residual-mode override (``MochaConfig.gram_max_d`` resolved by
-        the driver); None keeps the shared ``_solver_plan`` default.
+      gram: forced residual mode; None (what the driver passes) keeps the
+        shared ``_solver_plan`` rule.
     Returns (alpha', v') with the same shardings.  Jitted on the static
     (mesh, loss, max_steps, comm_dtype, gram), so every round after the
     first reuses one compiled program.
@@ -71,7 +67,7 @@ def distributed_round(mesh: Mesh, loss: Loss, max_steps: int,
     task_sharded = P("data")
     replicated = P()
     # the per-run hoisted row-norm table is task-major state like X; compute
-    # it here only for direct callers (dry-run lowerings) that skip run_mocha
+    # it here only for direct callers (dry-run lowerings) that skip _run_mocha
     from repro.core.subproblem import row_norms
     xnorm2 = data.xnorm2 if data.xnorm2 is not None else row_norms(data.X)
 
@@ -100,27 +96,6 @@ def distributed_round(mesh: Mesh, loss: Loss, max_steps: int,
     )
     return fn(data.X, data.y, data.mask, xnorm2, alpha, v, K, q_t, budgets,
               keys)
-
-
-def run_mocha_distributed(data: FederatedData, reg: "Regularizer",
-                          cfg: "MochaConfig", mesh: Optional[Mesh] = None,
-                          comm_dtype=None) -> "RunResult":
-    """Deprecated shim: construct a ``repro.api.Experiment`` with
-    ``Exec(engine='sharded', mesh=..., comm_dtype=...)`` instead.
-
-    Back-compat entry point (formerly ``repro.federated.simulator``); folded
-    into the same shim layer as ``run_mocha`` -- one deprecation path, one
-    warning message (repro.api.compat), bit-parity-tested in
-    tests/test_api.py.
-    """
-    from repro.api.compat import experiment_from_mocha, warn_legacy
-    from repro.core.engine import ShardedEngine
-    warn_legacy("run_mocha_distributed()",
-                "Exec(engine='sharded', mesh=..., comm_dtype=...)")
-    exp = experiment_from_mocha(
-        data, reg, cfg, engine=ShardedEngine(mesh=mesh,
-                                             comm_dtype=comm_dtype))
-    return exp.run(cfg.seed).result
 
 
 def lower_federated_round(mesh: Mesh, loss: Loss, max_steps: int,

@@ -26,9 +26,8 @@ def kernel_local_sdca(data, alpha, W, q_t, budgets, keys, max_steps,
                       interpret=None, gram=None):
     """Mirror of repro.core.subproblem.batched_local_sdca (hinge only).
 
-    ``gram`` is the residual-mode override (``MochaConfig.gram_max_d``
-    resolved by the driver); ``None`` keeps the shared ``_solver_plan``
-    default."""
+    ``gram`` forces a residual mode; ``None`` keeps the shared
+    ``_solver_plan`` rule."""
     if interpret is None:
         interpret = jax.default_backend() != "tpu"
     n_t = jnp.sum(data.mask, axis=1)

@@ -1,19 +1,21 @@
 """The unified experiment surface: capability routing, bit-parity against
-the legacy entry points, the sequential grid fallback, held-out evaluation,
-and Report provenance."""
+the core drivers, the spec->driver config mapping, the sequential grid
+fallback, held-out evaluation, and Report provenance."""
 import dataclasses
-import warnings
 
 import numpy as np
 import pytest
 
 import repro.api as api
-from repro.cohort import (CohortConfig, Population, PopulationSpec,
-                          run_mocha_cohort)
+from repro.cohort import (CohortConfig, FaultConfig, Population,
+                          PopulationSpec)
+from repro.cohort.driver import _run_cohort
 from repro.core import (BudgetConfig, MeanRegularized, MochaConfig,
-                        Probabilistic, per_task_error, run_mocha, run_sweep)
+                        Probabilistic, ShardedEngine, per_task_error)
 from repro.core.evaluate import evaluate_cohort, holdout_client_ids
 from repro.core.losses import get_loss
+from repro.core.mocha import _run_mocha
+from repro.core.sweep import _run_sweep
 from repro.core.systems_model import SystemsConfig
 from repro.data.synthetic import tiny_problem
 
@@ -152,7 +154,12 @@ def test_grid_fallback_rejects_mismatched_shuffles(problem):
         exp.run(seed=0)
 
 
-# -- bit-parity: Experiment.run vs the legacy entry points -------------------
+# -- bit-parity: Experiment.run vs the core drivers -------------------------
+#
+# Each case runs the same description twice: through Experiment.run, and
+# straight through the core driver with a hand-built MochaConfig /
+# CohortConfig -- the spec->driver mapping (as_mocha_config /
+# as_cohort_config) must not change a bit.
 
 @pytest.mark.parametrize("engine", ["local", "pallas", "sharded"])
 def test_experiment_matches_legacy_run_mocha(problem, engine):
@@ -161,19 +168,18 @@ def test_experiment_matches_legacy_run_mocha(problem, engine):
                       budget=BudgetConfig(passes=1.0, systems_lo=0.5,
                                           drop_prob=0.3),
                       record_every=4, seed=3, engine=engine)
-    with pytest.warns(DeprecationWarning, match="legacy entry point"):
-        legacy = run_mocha(train, REG, cfg)
+    core = _run_mocha(train, REG, cfg)
     rep = api.Experiment(
         problem=api.Problem(train=train),
         method=api.Method(loss="hinge", regularizers=(REG,), rounds=10,
                           budget=cfg.budget),
         exec=api.Exec(engine=engine),
         eval=api.Eval(record_every=4)).run(seed=3)
-    np.testing.assert_array_equal(legacy.W, rep.result.W)
-    np.testing.assert_array_equal(np.asarray(legacy.state.alpha),
+    np.testing.assert_array_equal(core.W, rep.result.W)
+    np.testing.assert_array_equal(np.asarray(core.state.alpha),
                                   np.asarray(rep.result.state.alpha))
-    assert legacy.history == rep.history
-    np.testing.assert_array_equal(legacy.round_budgets,
+    assert core.history == rep.history
+    np.testing.assert_array_equal(core.round_budgets,
                                   rep.result.round_budgets)
 
 
@@ -181,37 +187,34 @@ def test_experiment_matches_legacy_run_mocha_semi_sync(problem):
     train, _ = problem
     cfg = MochaConfig(loss="hinge", rounds=8, record_every=2, seed=5,
                       systems=SEMI)
-    with pytest.warns(DeprecationWarning, match="legacy entry point"):
-        legacy = run_mocha(train, REG, cfg)
+    core = _run_mocha(train, REG, cfg)
     rep = api.Experiment(
         problem=api.Problem(train=train),
         method=api.Method(loss="hinge", regularizers=(REG,), rounds=8),
         systems=api.Systems(config=SEMI),
         eval=api.Eval(record_every=2)).run(seed=5)
-    assert legacy.history == rep.history
+    assert core.history == rep.history
 
 
 def test_experiment_matches_legacy_run_sweep(shuffles):
     cfg = MochaConfig(loss="hinge", rounds=8, record_every=8, seed=0)
     regs = [MeanRegularized(lambda1=0.0, lambda2=lam) for lam in LAMBDAS]
     trains = [tr for tr, _ in shuffles]
-    with pytest.warns(DeprecationWarning, match="legacy entry point"):
-        legacy = run_sweep(trains, regs, (3, 4, 5), cfg)
+    core = _run_sweep(trains, regs, (3, 4, 5), cfg)
     rep = _grid_exp(shuffles).run(seed=(3, 4, 5))
     assert rep.provenance["path"] == "sweep"
-    np.testing.assert_array_equal(legacy.W, rep.result.W)
-    np.testing.assert_array_equal(legacy.gap, rep.result.gap)
-    assert legacy.seeds == rep.result.seeds
+    np.testing.assert_array_equal(core.W, rep.result.W)
+    np.testing.assert_array_equal(core.gap, rep.result.gap)
+    assert core.seeds == rep.result.seeds
 
 
-def test_experiment_matches_legacy_run_mocha_cohort():
+def test_experiment_matches_cohort_driver():
     pop = Population(POP_SPEC, seed=0)
     reg = Probabilistic(lam=1e-2, sigma2=10.0)
     cfg = CohortConfig(rounds=5, cohort=16, clusters=3, dropout=0.2,
                        omega_update_every=2, record_every=2, seed=1,
                        inner=MochaConfig(budget=BudgetConfig(passes=1.0)))
-    with pytest.warns(DeprecationWarning, match="legacy entry point"):
-        legacy = run_mocha_cohort(pop, reg, cfg)
+    core = _run_cohort(pop, reg, cfg)
     rep = api.Experiment(
         problem=api.Problem(population=pop),
         method=api.Method(loss="hinge", regularizers=(reg,), rounds=5,
@@ -220,25 +223,93 @@ def test_experiment_matches_legacy_run_mocha_cohort():
         systems=api.Systems(dropout=0.2),
         exec=api.Exec(cohort=16, clusters=3),
         eval=api.Eval(record_every=2)).run(seed=1)
-    assert legacy.history == rep.history
-    np.testing.assert_array_equal(legacy.centroids, rep.result.centroids)
-    np.testing.assert_array_equal(legacy.omega_k, rep.result.omega_k)
-    np.testing.assert_array_equal(legacy.assign, rep.result.assign)
+    assert core.history == rep.history
+    np.testing.assert_array_equal(core.centroids, rep.result.centroids)
+    np.testing.assert_array_equal(core.omega_k, rep.result.omega_k)
+    np.testing.assert_array_equal(core.assign, rep.result.assign)
 
 
 def test_legacy_distributed_shim_parity(problem):
     train, _ = problem
-    from repro.federated.runtime import run_mocha_distributed
     cfg = MochaConfig(loss="hinge", rounds=6, record_every=3, seed=2)
-    with pytest.warns(DeprecationWarning, match="legacy entry point"):
-        legacy = run_mocha_distributed(train, REG, cfg)
+    core = _run_mocha(train, REG, cfg, engine=ShardedEngine())
     rep = api.Experiment(
         problem=api.Problem(train=train),
         method=api.Method(loss="hinge", regularizers=(REG,), rounds=6),
         exec=api.Exec(engine="sharded"),
         eval=api.Eval(record_every=3)).run(seed=2)
-    np.testing.assert_array_equal(legacy.W, rep.result.W)
-    assert legacy.history == rep.history
+    np.testing.assert_array_equal(core.W, rep.result.W)
+    assert core.history == rep.history
+
+
+# -- the spec -> core-config mapping, field by field -------------------------
+
+#: (spec, field, non-default value, extra spec fields the value needs, the
+#: config-view attributes that must carry it).  One case per spec field that
+#: as_mocha_config / as_cohort_config reads.
+_CKPT = {"checkpoint_dir": "ckpt"}
+_SPEC_WIRING = [
+    ("method", "loss", "smooth_hinge", {},
+     ("mocha.loss", "cohort.inner.loss")),
+    ("method", "rounds", 7, {}, ("mocha.rounds", "cohort.rounds")),
+    ("method", "omega_update_every", 3, {},
+     ("mocha.omega_update_every", "cohort.omega_update_every")),
+    ("method", "gamma", 0.5, {}, ("mocha.gamma", "cohort.inner.gamma")),
+    ("method", "per_task_sigma", False, {},
+     ("mocha.per_task_sigma", "cohort.inner.per_task_sigma")),
+    ("method", "budget", BudgetConfig(passes=2.5, drop_prob=0.1), {},
+     ("mocha.budget", "cohort.inner.budget")),
+    ("systems", "network", "3g", {}, ("mocha.network", "cohort.network")),
+    ("systems", "config", SEMI, {}, ("mocha.systems", "cohort.systems")),
+    ("systems", "sampler", "weighted", {}, ("cohort.sampler",)),
+    ("systems", "dropout", 0.2, {}, ("cohort.dropout",)),
+    ("systems", "faults", FaultConfig(solve_fail_prob=0.1), {},
+     ("cohort.faults",)),
+    ("exec", "engine", "pallas", {},
+     ("mocha.engine", "cohort.inner.engine")),
+    ("exec", "driver", "loop", {}, ("mocha.driver", "cohort.inner.driver")),
+    ("exec", "cohort", 16, {}, ("cohort.cohort",)),
+    ("exec", "inner_rounds", 2, {}, ("cohort.inner_rounds",)),
+    ("exec", "clusters", 5, {}, ("cohort.clusters",)),
+    ("exec", "eta", 0.25, {}, ("cohort.eta",)),
+    ("exec", "cache_clients", 128, {}, ("cohort.cache_clients",)),
+    ("exec", "n_pad", 40, {}, ("cohort.n_pad",)),
+    ("exec", "overlap", 2, {}, ("cohort.overlap",)),
+    ("exec", "staleness", 1, {}, ("cohort.staleness",)),
+    ("exec", "max_retries", 2, {}, ("cohort.max_retries",)),
+    ("exec", "degrade", True, {}, ("cohort.degrade",)),
+    ("exec", "checkpoint_every", 3, _CKPT, ("cohort.checkpoint_every",)),
+    ("exec", "checkpoint_dir", "ckpt", {}, ("cohort.checkpoint_dir",)),
+    ("exec", "resume", True, _CKPT, ("cohort.resume",)),
+    ("exec", "telemetry", True, {}, ("cohort.telemetry",)),
+    ("exec", "trace_dir", "traces", {}, ("cohort.trace_dir",)),
+    ("eval", "record_every", 5, {},
+     ("mocha.record_every", "cohort.record_every")),
+]
+
+
+@pytest.mark.parametrize(
+    "spec,field,value,extra,carried", _SPEC_WIRING,
+    ids=[f"{sp}.{f}" for sp, f, *_ in _SPEC_WIRING])
+def test_spec_field_reaches_core_config(problem, spec, field, value, extra,
+                                        carried):
+    """Setting one spec field to a non-default value shows up in the core
+    drivers' config views -- the one wiring point between the specs and
+    the drivers."""
+    train, _ = problem
+    base = api.Experiment(problem=api.Problem(train=train))
+    sub = getattr(base, spec)
+    assert getattr(sub, field) != value           # the case is informative
+    exp = dataclasses.replace(base, **{spec: dataclasses.replace(
+        sub, **{field: value}, **extra)})
+    views = {"mocha": api.as_mocha_config(exp, seed=0),
+             "cohort": api.as_cohort_config(exp, seed=0)}
+    for path in carried:
+        view, *attrs = path.split(".")
+        got = views[view]
+        for attr in attrs:
+            got = getattr(got, attr)
+        assert got == value, f"{path} = {got!r}, want {value!r}"
 
 
 # -- the sequential grid fallback (the old ValueError walls) -----------------
@@ -371,14 +442,12 @@ def test_evaluate_rejects_unknown_metric(problem):
 
 def test_provenance_schema_and_gram_resolution(problem):
     from repro.api.report import PROVENANCE_KEYS
-    from repro.core.subproblem import active_gram_max_d
     train, _ = problem
     exp = api.Experiment(problem=api.Problem(train=train),
                          method=api.Method(regularizers=(REG,), rounds=2),
                          eval=api.Eval(record_every=2))
     rep = exp.run(0)
     assert set(rep.provenance) == set(PROVENANCE_KEYS)
-    assert rep.provenance["gram_max_d"] == active_gram_max_d()
     assert rep.provenance["gram_mode"] == "gram"      # d=6 <= crossover
     assert rep.provenance["fallback_reason"] is None
     # the config hash is stable across runs and moves when the spec moves
@@ -388,42 +457,16 @@ def test_provenance_schema_and_gram_resolution(problem):
         regularizers=(REG,), rounds=3))
     assert moved.run(0).provenance["config_hash"] != rep.provenance[
         "config_hash"]
-    # per-run crossover override is what provenance records
-    forced = dataclasses.replace(exp, exec=api.Exec(gram_max_d=4))
-    prov = forced.run(0).provenance
-    assert prov["gram_max_d"] == 4 and prov["gram_mode"] == "carry"
+    # the mode follows the problem's d on each side of the crossover
+    for d, mode in ((128, "gram"), (129, "carry")):
+        wide, _ = tiny_problem(m=2, n=4, d=d, seed=0)
+        prov = dataclasses.replace(
+            exp, problem=api.Problem(train=wide)).run(0).provenance
+        assert prov["gram_mode"] == mode, (d, prov["gram_mode"])
 
 
 def test_base_provenance_schema():
     from repro.api.report import PROVENANCE_KEYS
     base = api.base_provenance()
     assert set(base) == set(PROVENANCE_KEYS)
-    assert base["path"] is None and base["gram_max_d"] >= 1
-
-
-# -- the one deprecation path ------------------------------------------------
-
-def test_all_shims_share_one_warning_message(problem):
-    train, _ = problem
-    cfg = MochaConfig(loss="hinge", rounds=1, record_every=1)
-    msgs = set()
-    for call in (
-            lambda: run_mocha(train, REG, cfg),
-            lambda: run_sweep(api.Problem(train=[train]).stacked(), [REG], 0,
-                              cfg),
-            lambda: run_mocha_cohort(
-                Population(POP_SPEC, seed=0), REG,
-                CohortConfig(rounds=1, cohort=8, record_every=1)),
-    ):
-        with warnings.catch_warnings(record=True) as caught:
-            warnings.simplefilter("always")
-            call()
-        dep = [w for w in caught
-               if issubclass(w.category, DeprecationWarning)]
-        assert len(dep) == 1
-        text = str(dep[0].message)
-        assert text.startswith("legacy entry point ")
-        # one template: everything after the entry-point hint is shared
-        msgs.add(text.split(") and call ")[-1])
-        assert "repro.api.Experiment" in text
-    assert msgs == {".run() instead"}, f"shim messages drifted: {msgs}"
+    assert base["path"] is None and base["gram_mode"] is None

@@ -24,7 +24,7 @@ EXPECTED_FIELDS = {
     "Method": ("loss", "regularizers", "rounds", "omega_update_every",
                "gamma", "per_task_sigma", "budget", "budget_fn", "omega0"),
     "Systems": ("network", "config", "trace", "sampler", "dropout", "faults"),
-    "Exec": ("engine", "driver", "gram_max_d", "mesh", "comm_dtype",
+    "Exec": ("engine", "driver", "mesh", "comm_dtype",
              "state0", "cohort", "inner_rounds", "clusters", "eta",
              "cache_clients", "n_pad", "overlap", "staleness",
              "max_retries", "degrade", "checkpoint_every", "checkpoint_dir",
@@ -36,12 +36,13 @@ EXPECTED_FIELDS = {
     "Report": ("result", "provenance", "evaluation"),
 }
 
-#: the legacy config views are public surface too (thin views over the
-#: specs; CohortConfig.inner nests the per-block MochaConfig)
+#: the core drivers' config views are pinned too (built from the specs by
+#: as_mocha_config / as_cohort_config; CohortConfig.inner nests the
+#: per-block MochaConfig)
 EXPECTED_CONFIG_FIELDS = {
     MochaConfig: ("loss", "rounds", "omega_update_every", "gamma",
                   "per_task_sigma", "budget", "engine", "network", "systems",
-                  "seed", "record_every", "driver", "gram_max_d"),
+                  "seed", "record_every", "driver"),
     CohortConfig: ("rounds", "cohort", "inner_rounds", "sampler", "dropout",
                    "clusters", "eta", "omega_update_every", "cache_clients",
                    "network", "systems", "seed", "record_every", "n_pad",
@@ -74,8 +75,8 @@ def test_route_paths_and_provenance_keys_snapshot():
     assert api.PATHS == ("single", "sweep", "grid", "cohort")
     assert api.PROBLEM_KINDS == ("silo", "shuffles", "population")
     assert api.PROVENANCE_KEYS == ("path", "driver", "engine",
-                                   "fallback_reason", "gram_max_d",
-                                   "gram_mode", "config_hash", "backend",
-                                   "retries", "degraded_blocks",
-                                   "telemetry", "trace_path")
+                                   "fallback_reason", "gram_mode",
+                                   "config_hash", "backend", "retries",
+                                   "degraded_blocks", "telemetry",
+                                   "trace_path")
     assert api.METRICS == ("error", "loss")

@@ -9,9 +9,10 @@ import pytest
 
 from repro.cohort import (ClusterOmega, CohortConfig, CohortPacker,
                           CohortSampler, Population, PopulationSpec,
-                          StalenessBoundedMerger, pack_cohort,
-                          run_mocha_cohort)
-from repro.core import BudgetConfig, MochaConfig, Probabilistic, run_mocha
+                          StalenessBoundedMerger, pack_cohort)
+from repro.cohort.driver import _run_cohort
+from repro.core import BudgetConfig, MochaConfig, Probabilistic
+from repro.core.mocha import _run_mocha
 from repro.core.systems_model import (SystemsConfig, SystemsTrace,
                                       population_rates)
 from repro.data.synthetic import HUMAN_ACTIVITY
@@ -217,8 +218,8 @@ def _small_cfg(**kw):
 
 def test_cohort_run_bit_reproducible():
     pop = Population(SPEC, seed=0)
-    a = run_mocha_cohort(pop, REG, _small_cfg())
-    b = run_mocha_cohort(pop, REG, _small_cfg())
+    a = _run_cohort(pop, REG, _small_cfg())
+    b = _run_cohort(pop, REG, _small_cfg())
     assert a.history == b.history
     np.testing.assert_array_equal(a.centroids, b.centroids)
     np.testing.assert_array_equal(a.omega_k, b.omega_k)
@@ -231,8 +232,8 @@ def test_cohort_sharded_engine_matches_local():
     bit-identical to the local engine (cross-engine parity holds through
     the cohort layer)."""
     pop = Population(SPEC, seed=0)
-    loc = run_mocha_cohort(pop, REG, _small_cfg())
-    sh = run_mocha_cohort(pop, REG, _small_cfg(
+    loc = _run_cohort(pop, REG, _small_cfg())
+    sh = _run_cohort(pop, REG, _small_cfg(
         inner=MochaConfig(budget=BudgetConfig(passes=1.0),
                           engine="sharded")))
     assert loc.history == sh.history
@@ -245,7 +246,7 @@ def test_cohort_bounded_memory_structural():
     m, cache = 2000, 64
     pop = Population(dataclasses.replace(SPEC, m=m), seed=0)
     cfg = _small_cfg(cache_clients=cache)
-    res = run_mocha_cohort(pop, REG, cfg)
+    res = _run_cohort(pop, REG, cfg)
     state = res.relationship
     k, d, n_pad = cfg.clusters, SPEC.d, SPEC.pad_width
     assert state.omega_k.shape == (k, k)
@@ -267,9 +268,9 @@ def test_cohort_dropout_fault_tolerance():
     pop = Population(SPEC, seed=0)
     cfg = _small_cfg(rounds=12, dropout=0.5, record_every=1,
                      omega_update_every=0)
-    res = run_mocha_cohort(pop, REG, cfg)
+    res = _run_cohort(pop, REG, cfg)
     # drops visibly reduce coverage vs the no-failure run
-    full = run_mocha_cohort(pop, REG, dataclasses.replace(cfg, dropout=0.0))
+    full = _run_cohort(pop, REG, dataclasses.replace(cfg, dropout=0.0))
     assert res.final("unique_clients") < full.final("unique_clients")
     # and the cohort objective still improves despite 50% failures
     assert res.history["primal"][-1] < res.history["primal"][0]
@@ -318,7 +319,7 @@ def test_all_dropped_block_folds_zero_participation(monkeypatch):
         CohortSampler, "presample",
         lambda self, seed, rounds: orig(self, seed,
                                         rounds).with_all_dropped(dead))
-    pipe = run_mocha_cohort(pop, REG, dataclasses.replace(cfg, overlap=3))
+    pipe = _run_cohort(pop, REG, dataclasses.replace(cfg, overlap=3))
     assert pipe.schedule.dropped[dead].all()
     assert seq.history == pipe.history
     np.testing.assert_array_equal(seq.centroids, pipe.centroids)
@@ -341,7 +342,7 @@ def test_cohort_learns_cluster_structure():
     cfg = CohortConfig(rounds=40, cohort=32, clusters=3,
                        omega_update_every=10, record_every=40, seed=0,
                        inner=MochaConfig(budget=BudgetConfig(passes=2.0)))
-    res = run_mocha_cohort(pop, REG, cfg)
+    res = _run_cohort(pop, REG, cfg)
     ids = np.arange(spec.m)
     true = pop.true_assignments(ids)
     part = res.participation > 0
@@ -362,7 +363,7 @@ def test_cohort_small_cohorts_warm_every_cluster():
     cfg = CohortConfig(rounds=25, cohort=4, clusters=8, dropout=0.0,
                        record_every=25, seed=5,
                        inner=MochaConfig(budget=BudgetConfig(passes=1.0)))
-    res = run_mocha_cohort(pop, REG, cfg)
+    res = _run_cohort(pop, REG, cfg)
     assert (res.relationship.counts > 0).all(), res.relationship.counts
     # participation ground truth matches the schedule bound here (no drops)
     np.testing.assert_array_equal(
@@ -377,7 +378,7 @@ def test_cohort_participation_reflects_budget_drops():
     cfg = _small_cfg(rounds=10, dropout=0.0, record_every=10,
                      inner=MochaConfig(
                          budget=BudgetConfig(passes=1.0, drop_prob=0.5)))
-    res = run_mocha_cohort(pop, REG, cfg)
+    res = _run_cohort(pop, REG, cfg)
     sched = res.schedule.participation_counts(SPEC.m)
     assert res.participation.sum() < sched.sum()
     assert (res.participation <= sched).all()
@@ -389,9 +390,9 @@ def test_cohort_pipeline_staleness0_bit_identical():
     bit-identical to the sequential block loop -- state, history,
     participation, everything."""
     pop = Population(SPEC, seed=0)
-    seq = run_mocha_cohort(pop, REG, _small_cfg(rounds=8, record_every=1))
+    seq = _run_cohort(pop, REG, _small_cfg(rounds=8, record_every=1))
     for depth in (2, 4):
-        pipe = run_mocha_cohort(pop, REG, _small_cfg(
+        pipe = _run_cohort(pop, REG, _small_cfg(
             rounds=8, record_every=1, overlap=depth))
         assert seq.history == pipe.history
         np.testing.assert_array_equal(seq.centroids, pipe.centroids)
@@ -409,12 +410,12 @@ def test_cohort_pipeline_stale_merge_deterministic_and_bounded():
     execute."""
     pop = Population(SPEC, seed=0)
     cfg = _small_cfg(rounds=12, record_every=1, overlap=4, staleness=2)
-    a = run_mocha_cohort(pop, REG, cfg)
-    b = run_mocha_cohort(pop, REG, cfg)
+    a = _run_cohort(pop, REG, cfg)
+    b = _run_cohort(pop, REG, cfg)
     assert a.history == b.history                 # run-to-run bitwise
     np.testing.assert_array_equal(a.centroids, b.centroids)
     np.testing.assert_array_equal(a.assign, b.assign)
-    seq = run_mocha_cohort(pop, REG, dataclasses.replace(
+    seq = _run_cohort(pop, REG, dataclasses.replace(
         cfg, overlap=1, staleness=0))
     # the stale launches genuinely read older state...
     assert not np.array_equal(a.centroids, seq.centroids)
@@ -430,7 +431,7 @@ def test_cohort_participation_always_populated():
     a populated (m,) participation vector on every execution path."""
     pop = Population(SPEC, seed=0)
     for kw in ({}, {"overlap": 3}, {"overlap": 3, "staleness": 1}):
-        res = run_mocha_cohort(pop, REG, _small_cfg(rounds=3, **kw))
+        res = _run_cohort(pop, REG, _small_cfg(rounds=3, **kw))
         assert res.participation is not None
         assert res.participation.shape == (SPEC.m,)
         assert res.participation.sum() > 0
@@ -469,17 +470,17 @@ def test_cohort_full_participation_matches_run_mocha():
                        dropout=0.0, sampler="uniform", omega_update_every=0,
                        record_every=rounds, seed=4,
                        inner=MochaConfig(budget=BudgetConfig(passes=2.0)))
-    res_c = run_mocha_cohort(pop, REG, cfg)
+    res_c = _run_cohort(pop, REG, cfg)
 
     data = pack_cohort(pop, np.arange(m))
     om0 = float(np.asarray(REG.init_omega(1))[0, 0])
     omega_full = jnp.asarray(om0 * np.ones((m, m)) + eta * np.eye(m),
                              jnp.float32)
-    res_f = run_mocha(data, REG,
-                      MochaConfig(loss="hinge", rounds=rounds,
-                                  budget=BudgetConfig(passes=2.0),
-                                  record_every=rounds, seed=4),
-                      omega0=omega_full)
+    res_f = _run_mocha(data, REG,
+                       MochaConfig(loss="hinge", rounds=rounds,
+                                   budget=BudgetConfig(passes=2.0),
+                                   record_every=rounds, seed=4),
+                       omega0=omega_full)
     pc, pf = res_c.final("primal"), res_f.final("primal")
     assert abs(pc - pf) / abs(pf) < 2e-2
     # both runs actually descended: hinge P(0) = n_total at the cold start
@@ -490,7 +491,7 @@ def test_cohort_full_participation_matches_run_mocha():
 
 def test_cohort_history_schema():
     pop = Population(SPEC, seed=0)
-    res = run_mocha_cohort(pop, REG, _small_cfg())
+    res = _run_cohort(pop, REG, _small_cfg())
     from repro.cohort import COHORT_HISTORY_KEYS
     assert set(res.history) == set(COHORT_HISTORY_KEYS)
     lengths = {k: len(v) for k, v in res.history.items()}
@@ -544,8 +545,8 @@ def test_cohort_population_scale_100k():
                        systems=SystemsConfig(rate_lo=0.5, rate_hi=2.0),
                        record_every=5, seed=0, cache_clients=1024,
                        inner=MochaConfig(budget=BudgetConfig(passes=1.0)))
-    a = run_mocha_cohort(pop, REG, cfg)
-    b = run_mocha_cohort(pop, REG, cfg)
+    a = _run_cohort(pop, REG, cfg)
+    b = _run_cohort(pop, REG, cfg)
     assert a.history == b.history
     np.testing.assert_array_equal(a.centroids, b.centroids)
     np.testing.assert_array_equal(a.omega_k, b.omega_k)

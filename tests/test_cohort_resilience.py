@@ -9,7 +9,8 @@ import pytest
 
 from repro.cohort import (BlockFailure, CohortConfig, CohortPacker,
                           FaultConfig, FaultPlan, InjectedFault, Population,
-                          PopulationSpec, run_mocha_cohort)
+                          PopulationSpec)
+from repro.cohort.driver import _run_cohort
 from repro.cohort.resilience import (ASSUMPTION2_MAX_P, backoff_delay,
                                      run_fingerprint)
 from repro.core import BudgetConfig, MochaConfig, Probabilistic
@@ -107,7 +108,7 @@ def test_assumption2_guard_aborts_before_running():
     # end-to-end: the guard fires from the driver before any block runs
     pop = Population(SPEC, seed=0)
     with pytest.raises(ValueError, match="Assumption 2"):
-        run_mocha_cohort(pop, REG, _cfg(
+        _run_cohort(pop, REG, _cfg(
             degrade=True, faults=FaultConfig(solve_fail_prob=1.0)))
 
 
@@ -118,8 +119,8 @@ def test_zero_fault_path_bit_identical(tmp_path):
     degradation, checkpointing) must not perturb a single bit of the run --
     the wrappers reduce to the bare pack/solve calls."""
     pop = Population(SPEC, seed=0)
-    plain = run_mocha_cohort(pop, REG, _cfg())
-    armed = run_mocha_cohort(pop, REG, _cfg(
+    plain = _run_cohort(pop, REG, _cfg())
+    armed = _run_cohort(pop, REG, _cfg(
         max_retries=2, degrade=True, faults=FaultConfig()))
     assert plain.history == armed.history
     np.testing.assert_array_equal(plain.centroids, armed.centroids)
@@ -128,12 +129,12 @@ def test_zero_fault_path_bit_identical(tmp_path):
     np.testing.assert_array_equal(plain.participation, armed.participation)
     assert (armed.fault_stats.retries,
             armed.fault_stats.degraded_blocks) == (0, 0)
-    ck = run_mocha_cohort(pop, REG, _cfg(
+    ck = _run_cohort(pop, REG, _cfg(
         checkpoint_every=2, checkpoint_dir=str(tmp_path / "ck")))
     assert plain.history == ck.history
     np.testing.assert_array_equal(plain.centroids, ck.centroids)
     # and the pipelined loop keeps its staleness-0 parity with all of it on
-    piped = run_mocha_cohort(pop, REG, _cfg(
+    piped = _run_cohort(pop, REG, _cfg(
         overlap=3, max_retries=2, degrade=True, faults=FaultConfig(),
         checkpoint_every=2, checkpoint_dir=str(tmp_path / "ck2")))
     assert plain.history == piped.history
@@ -152,10 +153,10 @@ def test_retries_complete_with_plan_derived_counts():
     plan = FaultPlan.presample(faults, cfg.seed, cfg.rounds, cfg.max_retries)
     want_retries, want_degraded = _expected_counts(plan)
     assert want_retries > 0 and want_degraded == 0   # transient-only plan
-    res = run_mocha_cohort(pop, REG, cfg)
+    res = _run_cohort(pop, REG, cfg)
     assert res.fault_stats.retries == want_retries
     assert res.fault_stats.degraded_blocks == 0
-    ref = run_mocha_cohort(pop, REG, _cfg())
+    ref = _run_cohort(pop, REG, _cfg())
     # backoff charges push the simulated clock past the clean run...
     assert res.final("time") > ref.final("time")
     # ...and change NOTHING else: same solves, same folds, same coverage
@@ -172,7 +173,7 @@ def test_degraded_block_folds_as_dropped_nodes():
     seen/participation increment) and carried-forward metrics."""
     pop = Population(SPEC, seed=0)
     dead = 2
-    res = run_mocha_cohort(pop, REG, _cfg(
+    res = _run_cohort(pop, REG, _cfg(
         max_retries=1, degrade=True,
         faults=FaultConfig(solve_fail_blocks=(dead,))))
     assert res.fault_stats.degraded_blocks == 1
@@ -196,7 +197,7 @@ def test_degraded_block_folds_as_dropped_nodes():
 def test_block_failure_without_degradation_names_the_remedy():
     pop = Population(SPEC, seed=0)
     with pytest.raises(BlockFailure, match="degrade") as ei:
-        run_mocha_cohort(pop, REG, _cfg(
+        _run_cohort(pop, REG, _cfg(
             faults=FaultConfig(solve_fail_blocks=(1,))))
     assert (ei.value.block, ei.value.stage) == (1, "solve")
 
@@ -245,10 +246,10 @@ def test_injected_pack_faults_retry_through_the_pool(monkeypatch):
     federation through the draw pool as a clean serial-pack run."""
     pop = Population(SPEC, seed=0)
     _threads(monkeypatch, 1)
-    ref = run_mocha_cohort(pop, REG, _cfg())
+    ref = _run_cohort(pop, REG, _cfg())
     _threads(monkeypatch, 4)
     faults = FaultConfig(pack_fail_prob=0.4, seed=2)
-    res = run_mocha_cohort(pop, REG, _cfg(max_retries=3, faults=faults))
+    res = _run_cohort(pop, REG, _cfg(max_retries=3, faults=faults))
     assert res.fault_stats.retries > 0
     for key in ref.history:
         if key != "time":
@@ -281,7 +282,7 @@ def test_packers_release_their_draw_threads(monkeypatch):
     close theirs) leaves the process's thread count where it started."""
     _threads(monkeypatch, 4)
     pop = Population(SPEC, seed=0)
-    run_mocha_cohort(pop, REG, _cfg(rounds=1))     # warm JAX's own threads
+    _run_cohort(pop, REG, _cfg(rounds=1))     # warm JAX's own threads
     before = threading.active_count()
     for _ in range(12):
         packer = CohortPacker(pop, 16)
@@ -289,7 +290,7 @@ def test_packers_release_their_draw_threads(monkeypatch):
         packer.close()
         packer.close()                              # idempotent
     for overlap in (1, 2):
-        run_mocha_cohort(pop, REG, _cfg(rounds=2, overlap=overlap))
+        _run_cohort(pop, REG, _cfg(rounds=2, overlap=overlap))
     assert threading.active_count() == before
 
 
@@ -303,14 +304,14 @@ def test_checkpoint_resume_bit_identical(tmp_path, overlap, staleness):
     staleness) -- history, factored state, coverage, everything."""
     pop = Population(SPEC, seed=0)
     kw = dict(rounds=10, overlap=overlap, staleness=staleness)
-    ref = run_mocha_cohort(pop, REG, _cfg(**kw))
+    ref = _run_cohort(pop, REG, _cfg(**kw))
     ckdir = str(tmp_path / "ck")
     with pytest.raises(BlockFailure) as ei:
-        run_mocha_cohort(pop, REG, _cfg(
+        _run_cohort(pop, REG, _cfg(
             **kw, checkpoint_every=2, checkpoint_dir=ckdir,
             faults=FaultConfig(solve_fail_blocks=(6,))))
     assert (ei.value.block, ei.value.stage) == (6, "solve")
-    res = run_mocha_cohort(pop, REG, _cfg(
+    res = _run_cohort(pop, REG, _cfg(
         **kw, checkpoint_every=2, checkpoint_dir=ckdir, resume=True))
     assert res.resumed_from is not None and 0 <= res.resumed_from < 6
     assert res.history == ref.history
@@ -330,10 +331,10 @@ def test_resume_rejects_mismatched_config(tmp_path):
     settings must not."""
     pop = Population(SPEC, seed=0)
     ckdir = str(tmp_path / "ck")
-    run_mocha_cohort(pop, REG, _cfg(
+    _run_cohort(pop, REG, _cfg(
         rounds=4, checkpoint_every=2, checkpoint_dir=ckdir))
     with pytest.raises(ValueError, match="config hash"):
-        run_mocha_cohort(pop, REG, _cfg(
+        _run_cohort(pop, REG, _cfg(
             rounds=4, dropout=0.3, checkpoint_every=2, checkpoint_dir=ckdir,
             resume=True))
     base = _cfg(rounds=4)
@@ -356,7 +357,7 @@ def test_pipelined_solve_failure_folds_predecessors_and_checkpoints(tmp_path):
     pop = Population(SPEC, seed=0)
     ckdir = str(tmp_path / "ck")
     with pytest.raises(BlockFailure) as ei:
-        run_mocha_cohort(pop, REG, _cfg(
+        _run_cohort(pop, REG, _cfg(
             rounds=10, overlap=3, staleness=2, checkpoint_dir=ckdir,
             faults=FaultConfig(solve_fail_blocks=(5,))))
     assert (ei.value.block, ei.value.stage) == (5, "solve")
@@ -374,15 +375,15 @@ def test_pipelined_pack_failure_respects_drain_schedule(tmp_path):
     ckdir = str(tmp_path / "ck")
     fail, staleness = 4, 2
     with pytest.raises(BlockFailure) as ei:
-        run_mocha_cohort(pop, REG, _cfg(
+        _run_cohort(pop, REG, _cfg(
             rounds=10, overlap=3, staleness=staleness, checkpoint_dir=ckdir,
             faults=FaultConfig(pack_fail_blocks=(fail,))))
     assert (ei.value.block, ei.value.stage) == (fail, "pack")
     assert ckpt.latest_step(ckdir) == fail - 1 - staleness
     # and that checkpoint resumes to the reference bit-identically
-    ref = run_mocha_cohort(pop, REG, _cfg(rounds=10, overlap=3,
+    ref = _run_cohort(pop, REG, _cfg(rounds=10, overlap=3,
                                           staleness=staleness))
-    res = run_mocha_cohort(pop, REG, _cfg(
+    res = _run_cohort(pop, REG, _cfg(
         rounds=10, overlap=3, staleness=staleness, checkpoint_dir=ckdir,
         resume=True))
     assert res.resumed_from == fail - 1 - staleness

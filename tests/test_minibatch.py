@@ -3,7 +3,8 @@ import numpy as np
 import pytest
 
 from repro.core import (BudgetConfig, MeanRegularized, MiniBatchConfig,
-                        MochaConfig, run_mb_sdca, run_mb_sgd, run_mocha)
+                        MochaConfig, run_mb_sdca, run_mb_sgd)
+from repro.core.mocha import _run_mocha
 from repro.data.synthetic import tiny_problem
 
 REG = MeanRegularized(0.5, 0.5)
@@ -36,7 +37,7 @@ def test_mocha_beats_minibatch_in_rounds(problem):
     mechanism: mini-batch methods waste the communication budget)."""
     train, _ = problem
     rounds = 60
-    mocha = run_mocha(train, REG, MochaConfig(
+    mocha = _run_mocha(train, REG, MochaConfig(
         loss="hinge", rounds=rounds, budget=BudgetConfig(passes=1.0),
         record_every=rounds - 1))
     sgd = run_mb_sgd(train, REG, MiniBatchConfig(

@@ -8,7 +8,8 @@ import pytest
 
 from repro.core import (BudgetConfig, Clustered, Graphical, MeanRegularized,
                         MochaConfig, Probabilistic, duality_gap,
-                        per_task_error, run_cocoa, run_mocha)
+                        per_task_error, run_cocoa)
+from repro.core.mocha import _run_mocha
 from repro.data.synthetic import tiny_problem
 
 
@@ -26,7 +27,7 @@ def test_duality_gap_converges(problem, loss):
     train, _ = problem
     cfg = MochaConfig(loss=loss, rounds=80, budget=BudgetConfig(passes=2.0),
                       record_every=79)
-    res = run_mocha(train, REG, cfg)
+    res = _run_mocha(train, REG, cfg)
     rel_gap = res.final("gap") / max(abs(res.final("primal")), 1.0)
     assert rel_gap < 5e-3, f"{loss}: relative duality gap {rel_gap}"
 
@@ -35,7 +36,7 @@ def test_gap_monotone_trend(problem):
     train, _ = problem
     cfg = MochaConfig(loss="smooth_hinge", rounds=60,
                       budget=BudgetConfig(passes=1.0), record_every=5)
-    res = run_mocha(train, REG, cfg)
+    res = _run_mocha(train, REG, cfg)
     gaps = np.asarray(res.history["gap"])
     assert gaps[-1] < 1e-2 * gaps[0]
     # loose monotonicity: each recorded gap below 2x the previous
@@ -48,7 +49,7 @@ def test_linear_rate_for_smooth_losses(problem):
     train, _ = problem
     cfg = MochaConfig(loss="smooth_hinge", rounds=40,
                       budget=BudgetConfig(passes=2.0), record_every=1)
-    res = run_mocha(train, REG, cfg)
+    res = _run_mocha(train, REG, cfg)
     dual = np.asarray(res.history["dual"])
     d_star = dual[-1]
     subopt = dual - d_star
@@ -68,7 +69,7 @@ def test_straggler_budgets_still_converge(problem):
         loss="hinge", rounds=180,
         budget=BudgetConfig(passes=1.0, systems_lo=0.1, systems_hi=1.0),
         record_every=179)
-    res = run_mocha(train, REG, cfg)
+    res = _run_mocha(train, REG, cfg)
     rel_gap = res.final("gap") / max(abs(res.final("primal")), 1.0)
     assert rel_gap < 2e-2
 
@@ -79,7 +80,7 @@ def test_fault_tolerance_converges_under_assumption2(problem):
     cfg = MochaConfig(loss="hinge", rounds=250,
                       budget=BudgetConfig(passes=1.0, drop_prob=0.5),
                       record_every=249)
-    res = run_mocha(train, REG, cfg)
+    res = _run_mocha(train, REG, cfg)
     rel_gap = res.final("gap") / max(abs(res.final("primal")), 1.0)
     assert rel_gap < 2e-2
 
@@ -87,11 +88,11 @@ def test_fault_tolerance_converges_under_assumption2(problem):
 def test_permanently_dead_node_breaks_convergence(problem):
     """Fig 3 green line: a node with p = 1 forever -> wrong solution."""
     train, _ = problem
-    good = run_mocha(train, REG, MochaConfig(
+    good = _run_mocha(train, REG, MochaConfig(
         loss="hinge", rounds=80, budget=BudgetConfig(passes=2.0),
         record_every=79))
     with pytest.warns(UserWarning):
-        bad = run_mocha(train, REG, MochaConfig(
+        bad = _run_mocha(train, REG, MochaConfig(
             loss="hinge", rounds=80,
             budget=BudgetConfig(passes=2.0, never_send_node=0),
             record_every=79))
@@ -103,7 +104,7 @@ def test_permanently_dead_node_breaks_convergence(problem):
 
 def test_assumption2_validation_rejects_p1():
     with pytest.raises(ValueError):
-        run_mocha(tiny_problem()[0], REG, MochaConfig(
+        _run_mocha(tiny_problem()[0], REG, MochaConfig(
             budget=BudgetConfig(drop_prob=1.0)))
 
 
@@ -112,7 +113,7 @@ def test_cocoa_is_uniform_special_case(problem):
     train, _ = problem
     cfg = MochaConfig(loss="hinge", rounds=30, budget=BudgetConfig(passes=1.5),
                       per_task_sigma=False, record_every=29)
-    a = run_mocha(train, REG, cfg)
+    a = _run_mocha(train, REG, cfg)
     b = run_cocoa(train, REG, cfg)
     np.testing.assert_allclose(a.final("dual"), b.final("dual"), rtol=1e-5)
     np.testing.assert_allclose(np.asarray(a.W), np.asarray(b.W), atol=1e-5)
@@ -122,7 +123,7 @@ def test_gamma_less_than_one_converges(problem):
     train, _ = problem
     cfg = MochaConfig(loss="smooth_hinge", rounds=120, gamma=0.5,
                       budget=BudgetConfig(passes=1.0), record_every=119)
-    res = run_mocha(train, REG, cfg)
+    res = _run_mocha(train, REG, cfg)
     rel_gap = res.final("gap") / max(abs(res.final("primal")), 1.0)
     assert rel_gap < 1e-2
 
@@ -137,9 +138,9 @@ def test_omega_learning_improves_generalization():
                               omega_update_every=20,
                               budget=BudgetConfig(passes=2.0),
                               record_every=99)
-        mtl = run_mocha(train, Probabilistic(lam=0.01, sigma2=10.0), mtl_cfg)
-        local = run_mocha(train, MeanRegularized(lambda1=0.0, lambda2=0.01),
-                          dataclasses.replace(mtl_cfg, omega_update_every=0))
+        mtl = _run_mocha(train, Probabilistic(lam=0.01, sigma2=10.0), mtl_cfg)
+        local = _run_mocha(train, MeanRegularized(lambda1=0.0, lambda2=0.01),
+                           dataclasses.replace(mtl_cfg, omega_update_every=0))
         e_mtl.append(float(jnp.mean(per_task_error(
             train, jnp.asarray(mtl.W), test.X, test.y, test.mask))))
         e_loc.append(float(jnp.mean(per_task_error(
@@ -153,7 +154,7 @@ def test_history_columns_equal_length(problem, record_every):
     other keys followed record_every, yielding ragged history columns for any
     record_every > 1."""
     train, _ = problem
-    res = run_mocha(train, REG, MochaConfig(
+    res = _run_mocha(train, REG, MochaConfig(
         loss="hinge", rounds=11, budget=BudgetConfig(passes=0.5),
         record_every=record_every))
     lengths = {k: len(v) for k, v in res.history.items()}
@@ -170,7 +171,7 @@ def test_history_degenerate_cadences(problem, rounds, record_every, driver):
     must keep the final-round row and a rectangular history on BOTH
     drivers."""
     train, _ = problem
-    res = run_mocha(train, REG, MochaConfig(
+    res = _run_mocha(train, REG, MochaConfig(
         loss="hinge", rounds=rounds, record_every=record_every,
         driver=driver, budget=BudgetConfig(passes=0.5)))
     lengths = {len(v) for v in res.history.values()}
@@ -191,7 +192,7 @@ def test_record_rounds_validation():
 
 def test_history_time_axis_monotone(problem):
     train, _ = problem
-    res = run_mocha(train, REG, MochaConfig(
+    res = _run_mocha(train, REG, MochaConfig(
         loss="hinge", rounds=20, budget=BudgetConfig(passes=1.0),
         record_every=2))
     t = np.asarray(res.history["time"])
@@ -219,7 +220,7 @@ def test_compiled_omega_step_matches_eager_composition(problem, reg, warm):
     data = dual_mod.with_xnorm2(train)
     loss, gamma, m = get_loss("hinge"), 0.8, train.m
     if warm:   # a nonzero iterate, mid-run under the initial Omega
-        state = run_mocha(train, reg, MochaConfig(
+        state = _run_mocha(train, reg, MochaConfig(
             rounds=4, gamma=gamma, budget=BudgetConfig(passes=1.0))).state
     else:
         state = dual_mod.init_state(data)

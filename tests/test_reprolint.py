@@ -332,27 +332,6 @@ def test_p203_scan_host_materialization_negative(tmp_path):
         """) == []
 
 
-def test_p204_legacy_call_positive(tmp_path):
-    out = _lint(tmp_path, "benchmarks/foo.py", """\
-        from repro.core import run_mocha
-        def bench(data, cfg):
-            return run_mocha(data, cfg)
-        """)
-    assert _rules(out) == ["P204"]
-    assert len(out) == 1          # the call, never the import
-
-
-def test_p204_legacy_call_negative(tmp_path):
-    # re-exports are fine, and compat.py (the shim host) is exempt
-    assert _lint(tmp_path, "src/repro/__init__.py", """\
-        from repro.core import run_mocha  # noqa: F401
-        """) == []
-    assert _lint(tmp_path, "src/repro/api/compat.py", """\
-        def dispatch(data, cfg):
-            return run_mocha(data, cfg)
-        """) == []
-
-
 # -- T family ---------------------------------------------------------------
 
 _T_CLASS = """\
@@ -470,10 +449,11 @@ def test_w401_first_party_warning_positive(tmp_path):
     qs = tmp_path / "examples" / "quickstart.py"
     qs.parent.mkdir(parents=True)
     qs.write_text("import warnings\n"
-                  "warnings.warn('legacy entry point', DeprecationWarning)\n")
+                  "warnings.warn('old_call() is deprecated',"
+                  " DeprecationWarning)\n")
     findings, notes = quickstart.check_quickstart(tmp_path, target=qs)
     assert _rules(findings) == ["W401"]
-    assert "legacy entry point" in findings[0].snippet
+    assert "old_call() is deprecated" in findings[0].snippet
     assert notes == []
 
 

@@ -9,12 +9,12 @@ import numpy as np
 import pytest
 
 from repro.core import (HISTORY_KEYS, BudgetConfig, MeanRegularized,
-                        MochaConfig, PallasEngine, Probabilistic, get_engine,
-                        get_loss, run_mocha, sigma_prime)
+                        MochaConfig, PallasEngine, Probabilistic,
+                        ShardedEngine, get_engine, get_loss, sigma_prime)
+from repro.core.mocha import _run_mocha
 from repro.core.systems_model import SystemsConfig
 from repro.data.synthetic import tiny_problem
-from repro.federated.runtime import (distributed_round, make_federated_mesh,
-                                     run_mocha_distributed)
+from repro.federated.runtime import distributed_round, make_federated_mesh
 from repro.federated.sharding import pad_task_matrix, pad_tasks, pad_vector
 
 REG = MeanRegularized(0.5, 0.5)
@@ -30,7 +30,7 @@ def engine_runs():
         loss="hinge", rounds=12,
         budget=BudgetConfig(passes=1.0, systems_lo=0.5, drop_prob=0.3),
         record_every=4, seed=3)
-    return {e: run_mocha(train, REG, cfg, engine=e) for e in ENGINES}
+    return {e: _run_mocha(train, REG, cfg, engine=e) for e in ENGINES}
 
 
 def _assert_runs_bit_identical(a, b):
@@ -87,8 +87,8 @@ def test_engine_parity_scenarios(case, other):
     if case == "carry_mode":
         assert spec["problem"]["d"] > _GRAM_MAX_D
     train, _ = tiny_problem(**spec["problem"])
-    ref = run_mocha(train, REG, spec["cfg"], engine="local")
-    got = run_mocha(train, REG, spec["cfg"], engine=other)
+    ref = _run_mocha(train, REG, spec["cfg"], engine="local")
+    got = _run_mocha(train, REG, spec["cfg"], engine=other)
     _assert_runs_bit_identical(ref, got)
 
 
@@ -134,8 +134,8 @@ def test_scan_loop_driver_parity(case):
     round loop on a fixed seed: state, history, and executed budgets."""
     train, _ = tiny_problem(m=5, n=24, d=6, seed=2)
     cfg, reg = _PARITY_CASES[case]
-    loop = run_mocha(train, reg, dataclasses.replace(cfg, driver="loop"))
-    scan = run_mocha(train, reg, dataclasses.replace(cfg, driver="scan"))
+    loop = _run_mocha(train, reg, dataclasses.replace(cfg, driver="loop"))
+    scan = _run_mocha(train, reg, dataclasses.replace(cfg, driver="scan"))
     np.testing.assert_array_equal(np.asarray(loop.state.alpha),
                                   np.asarray(scan.state.alpha))
     np.testing.assert_array_equal(np.asarray(loop.state.v),
@@ -155,8 +155,8 @@ def test_scan_loop_parity_on_reused_trace():
     for driver in ("loop", "scan"):
         trace = SystemsTrace(train.m, train.d, SystemsConfig(network="lte"))
         trace.advance(np.full(train.m, 7))     # prior simulation activity
-        res = run_mocha(train, MeanRegularized(0.5, 0.5),
-                        dataclasses.replace(cfg, driver=driver), trace=trace)
+        res = _run_mocha(train, MeanRegularized(0.5, 0.5),
+                         dataclasses.replace(cfg, driver=driver), trace=trace)
         assert res.history["time"][0] > trace.events[0].duration_s
         histories[driver] = res.history
     assert histories["loop"] == histories["scan"]
@@ -166,9 +166,9 @@ def test_scan_driver_is_default_for_local():
     """driver='auto' takes the scanned path on LocalEngine and matches it."""
     train, _ = tiny_problem(m=4, n=16, d=5, seed=7)
     cfg = MochaConfig(loss="hinge", rounds=8, record_every=3, seed=2)
-    auto = run_mocha(train, MeanRegularized(0.5, 0.5), cfg)
-    scan = run_mocha(train, MeanRegularized(0.5, 0.5),
-                     dataclasses.replace(cfg, driver="scan"))
+    auto = _run_mocha(train, MeanRegularized(0.5, 0.5), cfg)
+    scan = _run_mocha(train, MeanRegularized(0.5, 0.5),
+                      dataclasses.replace(cfg, driver="scan"))
     assert auto.history == scan.history
     np.testing.assert_array_equal(auto.W, scan.W)
 
@@ -177,7 +177,7 @@ def test_scan_driver_rejected_without_capability():
     train, _ = tiny_problem(m=4, n=16, d=5, seed=7)
     cfg = MochaConfig(loss="hinge", rounds=2, engine="sharded", driver="scan")
     with pytest.raises(ValueError, match="scanned driver"):
-        run_mocha(train, MeanRegularized(0.5, 0.5), cfg)
+        _run_mocha(train, MeanRegularized(0.5, 0.5), cfg)
     assert not get_engine("sharded").supports_scan
     assert not get_engine("pallas").supports_scan
     assert get_engine("local").supports_scan
@@ -192,7 +192,7 @@ def test_engine_parity_dropped_node_through_pallas():
     def budget_fn(key, n_t, h):
         return jnp.full((4,), 10, jnp.int32).at[2].set(0)
 
-    res = {e: run_mocha(train, REG, cfg, engine=e, budget_fn=budget_fn)
+    res = {e: _run_mocha(train, REG, cfg, engine=e, budget_fn=budget_fn)
            for e in ENGINES}
     for e in ("pallas", "sharded"):
         np.testing.assert_array_equal(np.asarray(res["local"].state.v),
@@ -206,7 +206,7 @@ def test_pallas_engine_rejects_non_hinge():
     train, _ = tiny_problem(m=3, n=12, d=4, seed=0)
     cfg = MochaConfig(loss="logistic", rounds=2, engine="pallas")
     with pytest.raises(ValueError, match="hinge"):
-        run_mocha(train, REG, cfg)
+        _run_mocha(train, REG, cfg)
 
 
 def test_get_engine_resolution():
@@ -269,7 +269,7 @@ def test_distributed_driver_converges():
     train, _ = tiny_problem(m=5, n=24, d=6, seed=2)
     cfg = MochaConfig(loss="hinge", rounds=60, budget=BudgetConfig(passes=2.0),
                       record_every=59)
-    res = run_mocha_distributed(train, REG, cfg)
+    res = _run_mocha(train, REG, cfg, engine=ShardedEngine())
     rel_gap = res.final("gap") / max(abs(res.final("primal")), 1.0)
     assert rel_gap < 5e-3
 
@@ -278,44 +278,45 @@ def test_distributed_matches_serial_driver():
     train, _ = tiny_problem(m=6, n=20, d=6, seed=4)
     cfg = MochaConfig(loss="smooth_hinge", rounds=40,
                       budget=BudgetConfig(passes=1.0), record_every=39)
-    serial = run_mocha(train, REG, cfg)
-    dist = run_mocha_distributed(train, REG, cfg)
+    serial = _run_mocha(train, REG, cfg)
+    dist = _run_mocha(train, REG, cfg, engine=ShardedEngine())
     # identical problem, same convergence target; allow solver-path noise
     np.testing.assert_allclose(dist.final("primal"), serial.final("primal"),
                                rtol=1e-2)
 
 
-def test_simulator_alias_import_compatible():
-    """The folded-away repro.federated.simulator module must stay
-    import-compatible: same callable, DeprecationWarning on import."""
-    import importlib
-    import warnings
-
-    import repro.federated.simulator as sim
-    with warnings.catch_warnings(record=True) as caught:
-        warnings.simplefilter("always")
-        sim = importlib.reload(sim)
-    assert any(issubclass(w.category, DeprecationWarning) for w in caught)
-    assert sim.run_mocha_distributed is run_mocha_distributed
-
-
-def test_mocha_config_gram_max_d_threads_to_engines():
-    """cfg.gram_max_d resolves to the engines' gram override: forcing gram
-    mode above the default crossover stays bit-identical across engines
-    (the gram GEMM primitives are the context-stable ones)."""
+def test_forced_gram_threads_to_engines():
+    """A mode forced through ``setup(..., gram=True)`` reaches every
+    engine's solver: forced gram above the crossover (d=160) stays
+    bit-identical across engines (the gram GEMM primitives are the
+    context-stable ones) and differs from the default plan, which runs
+    carry there (same optimum, different association)."""
+    from repro.core import dual as dual_mod
+    from repro.core.mocha import _coupling_terms
     from repro.core.subproblem import _GRAM_MAX_D
     train, _ = tiny_problem(m=3, n=18, d=160, seed=2)
     assert train.d > _GRAM_MAX_D
-    cfg = MochaConfig(loss="hinge", rounds=6, record_every=3, seed=7,
-                      gram_max_d=256)
-    runs = {e: run_mocha(train, REG, cfg, engine=e) for e in ENGINES}
+    data, loss = dual_mod.with_xnorm2(train), get_loss("hinge")
+    max_steps = BudgetConfig().max_steps(train.n_max)
+    _, K, q_t = _coupling_terms(REG, REG.init_omega(train.m), 1.0, True,
+                                train.m)
+    budgets = jnp.asarray([max_steps, max_steps // 2, 5], jnp.int32)
+
+    def rounds(engine, gram):
+        eng = get_engine(engine)
+        state = eng.setup(data, loss, max_steps, gram=gram)
+        for r in range(3):
+            state = eng.round(state, K, q_t, budgets, 1.0,
+                              jax.random.PRNGKey(r))
+        return state
+
+    forced = {e: rounds(e, True) for e in ENGINES}
     for other in ("pallas", "sharded"):
-        _assert_runs_bit_identical(runs["local"], runs[other])
-    # the override changed the plan: default-crossover runs differ from the
-    # forced-gram runs in association, so trajectories must NOT be bitwise
-    # equal (they converge to the same optimum; only the mode flipped)
-    default = run_mocha(train, REG, dataclasses.replace(cfg, gram_max_d=None))
-    assert not np.array_equal(default.W, runs["local"].W)
+        for a, b in zip(forced["local"], forced[other]):
+            np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+    default = rounds("local", None)
+    assert not np.array_equal(np.asarray(default.v),
+                              np.asarray(forced["local"].v))
 
 
 def test_lowered_round_contains_all_gather():

@@ -275,20 +275,16 @@ def test_convergence_equivalent_to_v1_arithmetic(loss_name, gram):
                                rtol=1e-3, atol=2e-4)
 
 
-def test_gram_crossover_env_override(monkeypatch):
-    """REPRO_GRAM_MAX_D re-tunes the static residual-mode crossover (the
-    TPU re-tuning knob); resolve_gram turns MochaConfig.gram_max_d into the
-    engines' forced-mode override."""
-    from repro.core.subproblem import (_GRAM_MAX_D, _solver_plan,
-                                       active_gram_max_d, resolve_gram)
-    monkeypatch.delenv("REPRO_GRAM_MAX_D", raising=False)
-    assert active_gram_max_d() == _GRAM_MAX_D
-    assert _solver_plan(100, 256)[0] is True      # d=100 <= 128 -> gram
-    monkeypatch.setenv("REPRO_GRAM_MAX_D", "64")
-    assert active_gram_max_d() == 64
-    assert _solver_plan(100, 256)[0] is False     # d=100 > 64 -> carry
-    assert _solver_plan(100, 256, gram=True)[0] is True   # explicit wins
-    # config-field resolution: None defers, an int forces the comparison
-    assert resolve_gram(100, None) is None
-    assert resolve_gram(100, 200) is True
-    assert resolve_gram(100, 64) is False
+def test_gram_crossover_env_override():
+    """The residual-mode crossover has one home, ``_solver_plan``: gram at
+    d <= 128, carry above -- checked at the two benchmark widths (Vehicle
+    Sensor d=100, Human Activity d=561) and on each side of the boundary.
+    An explicit ``gram`` still forces a mode."""
+    from repro.core.subproblem import _GRAM_MAX_D, _solver_plan
+    assert _GRAM_MAX_D == 128
+    for d in (100, 128):
+        assert _solver_plan(d, 256)[0] is True
+    for d in (129, 561):
+        assert _solver_plan(d, 256)[0] is False
+    assert _solver_plan(561, 256, gram=True)[0] is True   # explicit wins
+    assert _solver_plan(100, 256, gram=False)[0] is False

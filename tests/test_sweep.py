@@ -6,9 +6,12 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+import repro.api as api
 from repro.core import (BudgetConfig, Clustered, MeanRegularized, MochaConfig,
-                        Probabilistic, per_task_error, run_mocha, run_sweep,
-                        stack_federations, sweep_errors)
+                        Probabilistic, per_task_error, stack_federations,
+                        sweep_errors)
+from repro.core.mocha import _run_mocha
+from repro.core.sweep import _run_sweep
 from repro.core.systems_model import SystemsConfig
 from repro.data.synthetic import tiny_problem
 
@@ -48,11 +51,11 @@ def test_sweep_matches_individual_runs_bitwise(shuffles):
                       record_every=15, seed=0)
     regs = [MeanRegularized(lambda1=0.0, lambda2=lam) for lam in LAMBDAS]
     trains = stack_federations([tr for tr, _ in shuffles])
-    res = run_sweep(trains, regs, 0, cfg)
+    res = _run_sweep(trains, regs, 0, cfg)
     assert res.W.shape == (3, 3, 5, 6)
     for li in range(len(LAMBDAS)):
         for s in range(3):
-            ref = run_mocha(shuffles[s][0], regs[li], cfg)
+            ref = _run_mocha(shuffles[s][0], regs[li], cfg)
             np.testing.assert_array_equal(res.W[li, s], ref.W)
             np.testing.assert_allclose(res.gap[li, s], ref.final("gap"),
                                        atol=2e-6)
@@ -65,10 +68,10 @@ def test_sweep_matches_individual_runs_with_omega_updates(shuffles):
                       budget=BudgetConfig(passes=1.0), record_every=16)
     regs = [Probabilistic(lam=lam, sigma2=10.0) for lam in LAMBDAS]
     trains = stack_federations([tr for tr, _ in shuffles])
-    res = run_sweep(trains, regs, 0, cfg)
+    res = _run_sweep(trains, regs, 0, cfg)
     for li in range(len(LAMBDAS)):
         for s in range(3):
-            ref = run_mocha(shuffles[s][0], regs[li], cfg)
+            ref = _run_mocha(shuffles[s][0], regs[li], cfg)
             scale = max(float(np.abs(ref.W).max()), 1.0)
             assert np.abs(res.W[li, s] - ref.W).max() / scale < 1e-3
             np.testing.assert_allclose(float(jnp.trace(
@@ -80,7 +83,7 @@ def test_sweep_errors_matches_per_task_error(shuffles):
     regs = [MeanRegularized(lambda1=0.0, lambda2=lam) for lam in LAMBDAS]
     trains = stack_federations([tr for tr, _ in shuffles])
     tests = stack_federations([te for _, te in shuffles])
-    res = run_sweep(trains, regs, 0, cfg)
+    res = _run_sweep(trains, regs, 0, cfg)
     errs = sweep_errors(res, tests)
     assert errs.shape == (3, 3)
     for li in (0, 2):
@@ -99,10 +102,10 @@ def test_sweep_per_shuffle_seeds(shuffles):
                                           drop_prob=0.2))
     regs = [MeanRegularized(lambda1=0.0, lambda2=1e-2)]
     trains = stack_federations([tr for tr, _ in shuffles])
-    res = run_sweep(trains, regs, [3, 4, 5], cfg)
+    res = _run_sweep(trains, regs, [3, 4, 5], cfg)
     for s, seed in enumerate((3, 4, 5)):
-        ref = run_mocha(shuffles[s][0], regs[0],
-                        dataclasses.replace(cfg, seed=seed))
+        ref = _run_mocha(shuffles[s][0], regs[0],
+                         dataclasses.replace(cfg, seed=seed))
         np.testing.assert_array_equal(res.W[0, s], ref.W)
 
 
@@ -113,13 +116,18 @@ def test_sweep_mixed_types_and_semi_sync_fall_back(shuffles):
     into the pre-sampled budgets; see test_api.py for the parity test)."""
     trains = stack_federations([tr for tr, _ in shuffles])
     cfg = MochaConfig(loss="hinge", rounds=2, record_every=2)
-    mixed = run_sweep(trains, [MeanRegularized(lambda1=0.0, lambda2=1e-2),
-                               Probabilistic(lam=1e-2)], 0, cfg)
-    assert mixed.W.shape == (2, 3, 5, 6)
+    mixed = api.Experiment(
+        problem=api.Problem(train=trains),
+        method=api.Method(regularizers=(
+            MeanRegularized(lambda1=0.0, lambda2=1e-2),
+            Probabilistic(lam=1e-2)), rounds=2),
+        eval=api.Eval(record_every=2)).run(0)
+    assert mixed.provenance["path"] == "grid"
+    assert mixed.result.W.shape == (2, 3, 5, 6)
     semi = dataclasses.replace(cfg, systems=SystemsConfig(
         policy="semi_sync", clock_cycle_s=0.1))
-    res = run_sweep(trains, [MeanRegularized(lambda1=0.0, lambda2=1e-2)], 0,
-                    semi)
+    res = _run_sweep(trains, [MeanRegularized(lambda1=0.0, lambda2=1e-2)], 0,
+                     semi)
     assert res.W.shape == (1, 3, 5, 6)
     assert np.isfinite(res.gap).all()
 
@@ -129,6 +137,6 @@ def test_sweep_degenerate_single_cell(shuffles):
     cfg = MochaConfig(loss="hinge", rounds=8, record_every=8)
     reg = Clustered(lam=0.5, eta=0.4, k=2)
     train = shuffles[0][0]
-    res = run_sweep(stack_federations([train]), [reg], 0, cfg)
-    ref = run_mocha(train, reg, cfg)
+    res = _run_sweep(stack_federations([train]), [reg], 0, cfg)
+    ref = _run_mocha(train, reg, cfg)
     np.testing.assert_array_equal(res.W[0, 0], ref.W)

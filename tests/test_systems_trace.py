@@ -4,8 +4,9 @@ import numpy as np
 import pytest
 
 from repro.core import (BudgetConfig, MeanRegularized, MochaConfig,
-                        SystemsConfig, SystemsTrace, run_mocha)
+                        SystemsConfig, SystemsTrace)
 from repro.core import systems_model
+from repro.core.mocha import _run_mocha
 from repro.data.synthetic import tiny_problem
 
 REG = MeanRegularized(0.5, 0.5)
@@ -97,9 +98,9 @@ def test_driver_semi_sync_caps_budgets():
     cycle = 8.5 * systems_model.SDCA_STEP_FLOPS(d) / systems_model.CLOCK_FLOPS
     base = MochaConfig(loss="hinge", rounds=10,
                        budget=BudgetConfig(passes=1.0), record_every=9)
-    sync = run_mocha(train, REG, base)
+    sync = _run_mocha(train, REG, base)
     import dataclasses
-    semi = run_mocha(train, REG, dataclasses.replace(
+    semi = _run_mocha(train, REG, dataclasses.replace(
         base, systems=SystemsConfig(policy="semi_sync", clock_cycle_s=cycle)))
     assert semi.round_budgets.max() == 8
     assert semi.round_budgets.max() < sync.round_budgets.max()
@@ -190,7 +191,7 @@ def test_clock_state_mid_round_guard():
 
 def test_driver_records_trace_and_budgets():
     train, _ = tiny_problem(m=4, n=16, d=5, seed=1)
-    res = run_mocha(train, REG, MochaConfig(
+    res = _run_mocha(train, REG, MochaConfig(
         loss="hinge", rounds=7, budget=BudgetConfig(passes=0.5),
         record_every=3))
     assert res.trace is not None and len(res.trace.events) == 7

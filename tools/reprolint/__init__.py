@@ -4,8 +4,7 @@ parity and thread-ownership invariants.
 Rule families (see ``python -m tools.reprolint --list-rules``):
 
   D1xx  determinism   no wall-clock / stdlib-random / unseeded-RNG reads
-  P2xx  parity        pinned Gram/row-dot primitives, traced round fns,
-                      no legacy entry-point calls
+  P2xx  parity        pinned Gram/row-dot primitives, traced round fns
   T3xx  threads       ``# owner:`` / ``# worker:`` cohort-pipeline contract
   U5xx  reachability  configs/models modules must justify their existence
   W4xx  quickstart    no first-party DeprecationWarnings (dynamic, opt-in)

@@ -3,14 +3,10 @@
 The one dynamic reprolint rule (it executes ``examples/quickstart.py``
 under a recording warnings filter, so it imports jax and takes seconds --
 hence opt-in via ``--quickstart`` rather than part of the static pass).
-The legacy entry points (``run_mocha`` & co.) are deprecated shims over
-``repro.api.Experiment``; first-party code -- the quickstart, the api
-execution paths it exercises, and everything they import -- must not
-route through them.  Third-party DeprecationWarnings (jax/numpy churn)
-are outside our control: reported as notes, never fatal.
-
-``tools/check_quickstart_warnings.py`` is the backward-compatible shim
-over this module.
+The quickstart, the ``repro.api`` execution paths it exercises, and
+everything they import must raise no DeprecationWarning of their own.
+Third-party DeprecationWarnings (jax/numpy churn) are outside our control:
+reported as notes, never fatal.
 """
 from __future__ import annotations
 
@@ -23,7 +19,7 @@ from typing import List, Optional, Tuple
 from tools.reprolint.findings import Finding
 
 RULE_ID = "W401"
-HINT = "route through repro.api.Experiment instead of the legacy shims"
+HINT = "replace the deprecated first-party call the warning names"
 
 REPO_ROOT = pathlib.Path(__file__).resolve().parents[2]
 
@@ -67,22 +63,3 @@ def check_quickstart(root: pathlib.Path = REPO_ROOT,
             notes.append(f"{w.filename}:{w.lineno}: {w.message}")
     return findings, notes
 
-
-def main(argv: Optional[List[str]] = None) -> int:
-    """Standalone gate (what check_quickstart_warnings.py always did)."""
-    findings, notes = check_quickstart()
-    for note in notes:
-        print(f"note: third-party DeprecationWarning ({note})")
-    if findings:
-        print("FAIL: DeprecationWarning raised from first-party code paths "
-              "(route through repro.api.Experiment instead):",
-              file=sys.stderr)
-        for f in findings:
-            print(f"  {f.path}:{f.line}: {f.snippet}", file=sys.stderr)
-        return 1
-    print("quickstart clean: no first-party DeprecationWarnings")
-    return 0
-
-
-if __name__ == "__main__":
-    sys.exit(main())

@@ -13,10 +13,9 @@ module must never import jax):
     one pinned order, which holds only while every engine goes through the
     fp_barrier'd chunk primitives in ``repro.core.subproblem``.  Raw
     re-derivations of those reductions (``X @ X.T``, manual row-dot sums)
-    in engine/kernel code silently fork the contract.  Host
+    in engine/kernel code silently fork the contract, and host
     materialization inside scanned round functions breaks ``lax.scan``
-    tracing, and legacy ``run_mocha``-family calls bypass the routed
-    ``repro.api`` surface.
+    tracing.
   * **T -- thread ownership**: the overlapped cohort pipeline
     (``repro.cohort.driver``) is race-free by a commented ownership
     contract: ``# owner: pack|solve|main`` on attribute initialisation,
@@ -581,33 +580,6 @@ class P203ScanHostMaterialization(Rule):
                             f"`{name}` materializes a traced value")
 
 
-class P204LegacyEntryCall(Rule):
-    id = "P204"
-    summary = ("call to a deprecated run_mocha-family entry point; "
-               "internal code must route through repro.api")
-    hint = ("use repro.api.Experiment (or the internal _run_mocha/"
-            "_run_sweep/_run_cohort) -- shims exist only for external "
-            "callers and warn via api/compat.py")
-    scope = ("src/repro/*", "benchmarks/*", "tools/*", "examples/*")
-    exempt = ("src/repro/api/compat.py", "tools/reprolint/*")
-
-    LEGACY = {"run_mocha", "run_sweep", "run_mocha_cohort",
-              "run_mocha_distributed"}
-
-    def check(self, ctx: FileContext) -> Iterator[Finding]:
-        for node in ast.walk(ctx.tree):
-            if not isinstance(node, ast.Call):
-                continue
-            terminal = None
-            if isinstance(node.func, ast.Name):
-                terminal = node.func.id
-            elif isinstance(node.func, ast.Attribute):
-                terminal = node.func.attr
-            if terminal in self.LEGACY:
-                yield ctx.finding(
-                    self, node, f"legacy entry-point call `{terminal}(...)`")
-
-
 # ---------------------------------------------------------------------------
 # T family -- thread ownership (cohort pipeline)
 
@@ -756,7 +728,7 @@ ALL_RULES: Tuple[Rule, ...] = (
     D104BenchProvenanceTime(), D105SilentFaultSwallow(),
     D106TelemetryDiscipline(), D107ServeReadOnly(),
     P201RawSelfGram(), P202ManualRowReduction(),
-    P203ScanHostMaterialization(), P204LegacyEntryCall(),
+    P203ScanHostMaterialization(),
     T301WrongWorkerAccess(), T302UntaggedOwnedWrite(),
 )
 
