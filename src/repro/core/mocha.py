@@ -191,15 +191,15 @@ def _run_mocha(data: FederatedData, reg: Regularizer, cfg: MochaConfig,
     their solve-worker view; the single path passes the run's main view):
     the whole run gets a ``mocha.run`` span holding ``mocha.setup`` (tagged
     with the SDCA loop's plan: ``residual_mode``, ``chunk``, ``max_steps``,
-    ``n_chunks``) and one ``mocha.omega_step`` per Omega step (one launch
-    of ``_omega_step``; ``compile`` is true on the run's first); the scanned
-    driver additionally records its presample / per-segment dispatch (first
-    dispatch = trace + compile) / host-pull / replay phases.  Once the run's
-    outputs are on the host, both drivers count its coordinate steps:
-    ``sdca.steps_required`` (the executed budgets' sum) and
-    ``sdca.steps_lockstep`` (the trips the vmapped loop ran, masked ones
-    included).  Telemetry only READS state -- results are bit-identical
-    with it on, off, or absent.
+    ``n_chunks``, ``accumulator``) and one ``mocha.omega_step`` per Omega
+    step (one launch of ``_omega_step``; ``compile`` is true on the run's
+    first); the scanned driver additionally records its presample /
+    per-segment dispatch (first dispatch = trace + compile) / host-pull /
+    replay phases.  Once the run's outputs are on the host, both drivers
+    count its coordinate steps: ``sdca.steps_required`` (the executed
+    budgets' sum) and ``sdca.steps_lockstep`` (the trips the vmapped loop
+    ran, masked ones included).  Telemetry only READS state -- results are
+    bit-identical with it on, off, or absent.
     """
     loss = get_loss(cfg.loss)
     validate_assumption2(cfg.budget)
@@ -213,7 +213,7 @@ def _run_mocha(data: FederatedData, reg: Regularizer, cfg: MochaConfig,
     tel = telemetry if telemetry is not None else obs.NULL_TELEMETRY
     scanned = cfg.driver != "loop" and eng.supports_scan
     run = _run_scanned if scanned else _run_loop
-    from repro.core.subproblem import _solver_plan, n_chunks
+    from repro.core.subproblem import _solver_plan, accumulator, n_chunks
     max_steps = cfg.budget.max_steps(data.n_max)
     # the SDCA loop's static plan as every engine derives it
     gram_mode, chunk = _solver_plan(data.d, max_steps)
@@ -222,7 +222,8 @@ def _run_mocha(data: FederatedData, reg: Regularizer, cfg: MochaConfig,
                   driver="scan" if scanned else "loop"):
         with tel.span("mocha.setup",
                       residual_mode="gram" if gram_mode else "carry",
-                      chunk=chunk, max_steps=max_steps, n_chunks=chunks):
+                      chunk=chunk, max_steps=max_steps, n_chunks=chunks,
+                      accumulator=accumulator(data.n_max)):
             # hoist the static per-run SDCA precompute (row-norm table)
             # ONCE: the data never changes across rounds, and every
             # engine/driver below reads the same table, which also keeps it

@@ -107,6 +107,13 @@ def _solver_plan(d: int, max_steps: int,
     return gram, max(1, min(C, max_steps))
 
 
+def accumulator(n: int) -> str:
+    """The chunk accumulator a solve over ``n`` points runs: ``"position"``
+    (compact, one slot per chunk position) at and above
+    ``_CHUNK_THRESHOLD``, ``"dense"`` (the carried (n,) buffer) below."""
+    return "position" if n >= _CHUNK_THRESHOLD else "dense"
+
+
 def n_chunks(max_steps: int, C: int) -> int:
     """Chunks of ``C`` that a stream of ``max_steps`` draws is laid out in
     (``chunk_idx_stream``): each task runs ``n_chunks * C`` lockstep trips
@@ -121,8 +128,9 @@ class ChunkPlan(NamedTuple):
                   ``max_steps`` (padded steps sit past every clamped budget,
                   so they are never live).
     ``firstpos``: (n_chunks, C) position of the first occurrence of each
-                  coordinate within its chunk -- repeated draws accumulate
-                  into one compact slot so later steps see earlier deltas.
+                  coordinate within its chunk -- the positions of one
+                  coordinate share it, so an add goes to all of them and
+                  later steps see earlier deltas.
     ``wb``:       (n_chunks, C) write-back scatter target: the coordinate at
                   first occurrences, ``n`` (out of bounds -> dropped)
                   elsewhere.
@@ -265,10 +273,14 @@ def _run_chunks(loss: Loss, X_t: Array, y_t: Array, mask_t: Array,
     """The arithmetic-v2 chunk loop, shared by both accumulator variants.
 
     ``compact=False`` (dense) scatters each delta straight into the carried
-    (n,) dalpha buffer; ``compact=True`` accumulates into a chunk-local
-    buffer indexed by first occurrence and writes back once per chunk.  The
-    adds hit the same values in the same order either way, so the variants
-    are bit-identical (tests/test_subproblem.py).
+    (n,) dalpha buffer; ``compact=True`` keeps a chunk-local buffer indexed
+    by chunk position, adds each delta by a select to every position that
+    holds the step's coordinate, and writes the first occurrences back once
+    per chunk.  Every position of a coordinate takes the adds the dense
+    buffer's element takes, in the same order, so the variants are
+    bit-identical (tests/test_subproblem.py) and the compact step reads its
+    own position with a static index: no gather and no scatter-add.  The
+    ``deltas`` write stays a scatter (DESIGN.md section 3a).
 
     ``unroll_chunks`` replaces the chunk ``fori_loop`` with a python loop
     (bit-identical; the body is pure).  XLA's HLO cost analysis counts a
@@ -293,21 +305,25 @@ def _run_chunks(loss: Loss, X_t: Array, y_t: Array, mask_t: Array,
             p = _chunk_rowdots(Xc, r)
         if compact:
             fpos, wb = plan.firstpos[c], plan.wb[c]
-            acc = dalpha[ic]              # running totals, compacted
+            acc = dalpha[ic]              # running totals, one per position
         else:
             acc = dalpha
         deltas = jnp.zeros((C,), X_t.dtype)
         # unrolled: s is static, so every chunk-local index below is static
         for s in range(C):
-            k = fpos[s] if compact else ic[s]
-            a = ac[s] + acc[k]
+            a = ac[s] + (acc[s] if compact else acc[ic[s]])
             g = (_gram_g(p[s], q_t, G[s], deltas) if gram
                  else _carry_g(Xc[s], r))
             delta = loss.sdca_delta(a, yc[s], g, q_t * xc2[s])
             live = ((c * C + s < budget_t)
                     & (mc[s] > 0)).astype(delta.dtype)
             delta = delta * live
-            acc = acc.at[k].add(delta)
+            if compact:
+                # every position holding this coordinate takes the add: a
+                # select, where a scatter-add would cost a launch a step
+                acc = jnp.where(fpos == fpos[s], acc + delta, acc)
+            else:
+                acc = acc.at[ic[s]].add(delta)
             deltas = deltas.at[s].set(delta)
             if not gram:
                 r = _carry_step_r(r, q_t, delta, Xc[s])
@@ -343,7 +359,7 @@ def _local_sdca_chunked(loss: Loss, X_t: Array, y_t: Array, mask_t: Array,
                         budget_t: Array, idx: Array, max_steps: int,
                         xnorm2: Array, gram: bool, C: int,
                         unroll_chunks: bool = False) -> Tuple[Array, Array]:
-    """Large-n variant: compact first-occurrence accumulator, one (n,)
+    """Large-n variant: compact position-indexed accumulator, one (n,)
     write-back per chunk instead of one O(n) carry copy per step."""
     return _run_chunks(loss, X_t, y_t, mask_t, alpha_t, w_t, q_t, budget_t,
                        idx, max_steps, xnorm2, gram, C, compact=True,
@@ -367,7 +383,7 @@ def local_sdca_idx(loss: Loss, X_t: Array, y_t: Array, mask_t: Array,
     if xnorm2 is None:
         xnorm2 = row_norms(X_t)
     gram, C = _solver_plan(X_t.shape[1], max_steps, gram)
-    solver = (_local_sdca_chunked if X_t.shape[0] >= _CHUNK_THRESHOLD
+    solver = (_local_sdca_chunked if accumulator(X_t.shape[0]) == "position"
               else _local_sdca_dense)
     return solver(loss, X_t, y_t, mask_t, alpha_t, w_t, q_t, budget_t,
                   idx, max_steps, xnorm2, gram, C,

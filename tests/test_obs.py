@@ -599,7 +599,8 @@ def test_setup_args_and_sdca_counters_follow_the_solver_plan(tmp_path, mode,
         setup, = (ev for ev in json.load(fh)["traceEvents"]
                   if ev.get("cat") == "wall" and ev["name"] == "mocha.setup")
     assert setup["args"] == {"residual_mode": mode, "chunk": C,
-                             "max_steps": steps, "n_chunks": n_chunks}
+                             "max_steps": steps, "n_chunks": n_chunks,
+                             "accumulator": "dense"}
     counters = traced.provenance["telemetry"]
     assert counters["sdca.steps_required"] == int(
         traced.result.round_budgets.sum())
@@ -607,3 +608,31 @@ def test_setup_args_and_sdca_counters_follow_the_solver_plan(tmp_path, mode,
     np.testing.assert_array_equal(plain.result.W, traced.result.W)
     np.testing.assert_array_equal(plain.result.round_budgets,
                                   traced.result.round_budgets)
+
+
+@pytest.mark.parametrize("n,accumulator", [(48, "dense"), (176, "position")])
+def test_setup_args_name_the_sdca_accumulator(tmp_path, n, accumulator):
+    """``mocha.setup``'s ``accumulator`` names the chunk accumulator the
+    solver runs: ``position`` at and above ``_CHUNK_THRESHOLD`` training
+    points per task (132 here), ``dense`` below (36); nothing is recorded
+    with telemetry off."""
+    from repro.api import Exec, Experiment, Method, Problem
+    from repro.core.subproblem import _CHUNK_THRESHOLD
+    from repro.data.synthetic import tiny_problem
+    train, _ = tiny_problem(m=2, n=n, d=5, seed=3)
+    assert (train.n_max >= _CHUNK_THRESHOLD) == (accumulator == "position")
+
+    def run(**exec_kw):
+        return Experiment(
+            problem=Problem(train=train),
+            method=Method(regularizers=[REG], rounds=2,
+                          budget=BudgetConfig(passes=1.0)),
+            exec=Exec(**exec_kw)).run(seed=4)
+
+    traced, plain = run(trace_dir=str(tmp_path)), run()
+    with open(traced.provenance["trace_path"]) as fh:
+        setup, = (ev for ev in json.load(fh)["traceEvents"]
+                  if ev.get("cat") == "wall" and ev["name"] == "mocha.setup")
+    assert setup["args"]["accumulator"] == accumulator
+    assert plain.provenance["trace_path"] is None
+    np.testing.assert_array_equal(plain.result.W, traced.result.W)

@@ -139,7 +139,7 @@ def _both_variants(loss, X, y, mask, alpha, w, q, budget, idx, max_steps,
 @pytest.mark.parametrize("gram", [False, True], ids=["carry", "gram"])
 @pytest.mark.parametrize("loss_name", ["hinge", "smooth_hinge", "logistic"])
 def test_chunked_solver_bit_identical_to_dense(loss_name, gram):
-    """The compact first-occurrence accumulator and the dense per-step
+    """The compact position-indexed accumulator and the dense per-step
     scatter must be bit-identical under BOTH residual modes (same draws,
     same adds, same order -- DESIGN.md section 2)."""
     rng = np.random.default_rng(3)
@@ -153,6 +153,49 @@ def test_chunked_solver_bit_identical_to_dense(loss_name, gram):
     np.testing.assert_array_equal(np.asarray(u_d), np.asarray(u_c))
 
 
+def _gather_scatter_add_chunks(loss, X, y, mask, alpha, w, q, budget, idx,
+                               max_steps, xnorm2, gram, C):
+    """The compact chunk loop with the step it had before the
+    position-indexed accumulator: one slot per coordinate, at its first
+    position in the chunk, read by ``acc[fpos[s]]`` and updated by a
+    scatter-add.  The reference the select-based step must equal bit for
+    bit (DESIGN.md section 3a)."""
+    from repro.core import subproblem as sp
+    n, d = X.shape
+    budget = jnp.minimum(budget, max_steps)
+    plan = sp._chunk_layout(idx, n, max_steps, C)
+
+    def chunk_body(c, carry):
+        dalpha, u, r = carry
+        ic, fpos, wb = plan.idx_c[c], plan.firstpos[c], plan.wb[c]
+        Xc = X[ic]
+        yc, xc2, mc, ac = y[ic], xnorm2[ic], mask[ic], alpha[ic]
+        if gram:
+            G, p = sp._chunk_gram(Xc), sp._chunk_rowdots(Xc, r)
+        acc = dalpha[ic]
+        deltas = jnp.zeros((C,), X.dtype)
+        for s in range(C):
+            k = fpos[s]
+            g = (sp._gram_g(p[s], q, G[s], deltas) if gram
+                 else sp._carry_g(Xc[s], r))
+            delta = loss.sdca_delta(ac[s] + acc[k], yc[s], g, q * xc2[s])
+            live = ((c * C + s < budget) & (mc[s] > 0)).astype(delta.dtype)
+            delta = delta * live
+            acc = acc.at[k].add(delta)
+            deltas = deltas.at[s].set(delta)
+            if not gram:
+                r = sp._carry_step_r(r, q, delta, Xc[s])
+        colsum = sp._chunk_colsum(Xc, deltas)
+        if gram:
+            r = sp._gram_chunk_r(r, q, colsum)
+        return dalpha.at[wb].set(acc, mode="drop"), u + colsum, r
+
+    carry = (jnp.zeros(n, X.dtype), jnp.zeros(d, X.dtype), w)
+    dalpha, u, _ = jax.lax.fori_loop(0, plan.idx_c.shape[0], chunk_body,
+                                     carry)
+    return dalpha, u
+
+
 # ---------------------------------------------------------------------------
 # chunk-boundary coverage: the firstpos/write-back dedup logic at its edges
 # ---------------------------------------------------------------------------
@@ -163,33 +206,83 @@ def test_chunked_solver_bit_identical_to_dense(loss_name, gram):
     "steps_lt_chunk",      # max_steps < C: single short chunk
     "steps_not_multiple",  # ragged tail chunk (padded steps must stay dead)
     "repeat_heavy",        # tiny n, large budget: every chunk full of repeats
+    "repeat_heavy_compact",  # n just above the threshold, about six
+                             # passes, one coordinate 4 times in each chunk
 ])
 def test_chunk_boundaries_bit_identical(case, gram):
-    from repro.core.subproblem import _CHUNK_THRESHOLD, _solver_plan
+    from repro.core.subproblem import (_CHUNK_THRESHOLD, _chunk_layout,
+                                       _solver_plan, row_norms)
     rng = np.random.default_rng(11)
     n, max_steps = {
         "n_eq_threshold": (_CHUNK_THRESHOLD, 2 * _CHUNK_THRESHOLD),
         "steps_lt_chunk": (40, 5),
         "steps_not_multiple": (50, 101),
         "repeat_heavy": (3, 400),
+        "repeat_heavy_compact": (_CHUNK_THRESHOLD + 2, 6 * _CHUNK_THRESHOLD),
     }[case]
     d = 9
     loss, X, y, mask, alpha, w = _toy("hinge", n, d, seed=12, mask_frac=0.9)
     idx = jnp.asarray(rng.integers(0, n, max_steps), jnp.int32)
     budget = jnp.asarray(rng.integers(0, max_steps + 3), jnp.int32)
+    g, C = _solver_plan(d, max_steps, gram)
+    if case == "repeat_heavy_compact":
+        # every 16 draws repeat their second coordinate three more times,
+        # so each chunk (C = 16 or 32) holds one coordinate 4 times or more
+        planted = np.array(idx).reshape(-1, 16)
+        planted[:, [5, 9, 13]] = planted[:, [1]]
+        idx = jnp.asarray(planted.reshape(-1))
+        budget = jnp.asarray(max_steps - 5, jnp.int32)
+        counts = [np.bincount(row).max() for row in np.asarray(
+            _chunk_layout(idx, n, max_steps, C).idx_c)]
+        assert min(counts) >= 3
+    q = jnp.asarray(0.9)
     (da_d, u_d), (da_c, u_c) = _both_variants(
-        loss, X, y, mask, alpha, w, jnp.asarray(0.9), budget, idx, max_steps,
-        gram)
+        loss, X, y, mask, alpha, w, q, budget, idx, max_steps, gram)
     np.testing.assert_array_equal(np.asarray(da_d), np.asarray(da_c))
     np.testing.assert_array_equal(np.asarray(u_d), np.asarray(u_c))
+    # ... and the gather / scatter-add step the select replaced
+    da_o, u_o = jax.jit(_gather_scatter_add_chunks,
+                        static_argnums=(0, 9, 11, 12))(
+        loss, X, y, mask, alpha, w, q, budget, idx, max_steps, row_norms(X),
+        g, C)
+    assert np.array_equal(np.asarray(da_o), np.asarray(da_c))
+    assert np.array_equal(np.asarray(u_o), np.asarray(u_c))
     # repeated-coordinate totals must match a sequential numpy replay count:
     # every live draw contributes exactly once to its coordinate's total
-    if case == "repeat_heavy":
+    if case.startswith("repeat_heavy"):
         live = (np.arange(max_steps) < int(budget)) \
             & (np.asarray(mask)[np.asarray(idx)] > 0)
         touched = np.zeros(n, bool)
         touched[np.asarray(idx)[live]] = True
         assert np.all((np.asarray(da_d) != 0) <= touched)
+
+
+@pytest.mark.parametrize("d", [100, 561], ids=["gram", "carry"])
+def test_compact_step_has_no_gather_and_one_scatter(d):
+    """The compact step reads and updates its accumulator by static index
+    and select: in the vmapped solve the engines run, lowered on the CPU,
+    the chunk's gathers do not grow with the chunk's C unrolled steps, and
+    its scatters are one a step (the ``deltas`` write) plus the chunk's
+    write-back.  ``max_steps`` below the mode's chunk width sets C."""
+    from repro.core.subproblem import (_CHUNK_THRESHOLD, _solver_plan,
+                                       accumulator, batched_local_sdca)
+    m, n = 2, _CHUNK_THRESHOLD + 2
+    assert accumulator(n) == "position"
+
+    def sds(*shape, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(shape, dtype)
+
+    counts = {}
+    for steps in (8, 24):
+        assert _solver_plan(d, steps) == (d <= 128, steps)
+        text = jax.jit(batched_local_sdca, static_argnums=(0, 9)).lower(
+            get_loss("hinge"), sds(m, n, d), sds(m, n), sds(m, n),
+            sds(m, n), sds(m, d), sds(m), sds(m, dtype=jnp.int32),
+            sds(m, 2, dtype=jnp.uint32), steps, sds(m, n)).as_text()
+        counts[steps] = (text.count('"stablehlo.gather"('),
+                         text.count('"stablehlo.scatter"('))
+    assert counts[8][0] == counts[24][0]
+    assert counts[8][1] == 8 + 1 and counts[24][1] == 24 + 1
 
 
 def test_dispatch_uses_chunked_at_threshold():
